@@ -54,6 +54,8 @@ def main() -> None:
     # must precede the section imports below (they import jax); kept inside
     # main() so merely importing this module has no environment side effect
     _bench_env()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (chaos_bench, durable_resume, fig1_speed,
                             pipeline_bench, serve_decode, shard_scaling,
                             sketch_fusion, stats_onepass, stream_scaling,

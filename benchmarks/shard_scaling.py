@@ -1,6 +1,6 @@
 """Multi-device scaling of the sharded sketch data-plane (PR 3).
 
-Three measurements, all parity-asserted before timing so a speedup is never
+Two measurements, both parity-asserted before timing so a speedup is never
 measured against a semantically different computation:
 
 * **sharded signing sweep** — one MinHash plan over a (B, S) batch through
@@ -10,12 +10,6 @@ measured against a semantically different computation:
 * **batched dedup** — ``MinHashDeduper.add_batch`` with the ``data_shards``
   knob on vs off (sharded signing + band-sharded LSH probing vs the
   single-device path), identical flags asserted.
-* **lane-tiled MinHash remix** — the fused kernel's k=64 signature pass at
-  the block_s the lane-tiled budget admits vs the widest tile the old
-  full-k ``(block_b, block_s, k)`` budget allowed (interpret mode off-TPU;
-  the admitted-tile numbers are the architectural point). k<=16 plans run a
-  single lane chunk — the exact pre-lane-tiling computation — so there is
-  no regression to measure, only to assert.
 
 Virtual CPU devices share the host's physical cores, so CPU wall-clock
 scaling is bounded by core count (this container has few); the sweep still
@@ -34,8 +28,6 @@ import numpy as np
 from repro.data.dedup import DedupConfig, MinHashDeduper
 from repro.kernels import api, shard
 from repro.kernels.plan import HashSpec, MinHashSpec, SketchPlan
-from repro.kernels.sketch_fused import (_MINHASH_LANE_TILE, _budget_cap,
-                                        _resolve_block_s, sketch_plan_fused)
 
 
 def _timeit(fn, reps=5):
@@ -126,45 +118,6 @@ def _dedup_rows(n_docs: int = 512, doc_len: int = 1024):
     ]
 
 
-def _remix_rows(B: int = 8, S: int = 2048):
-    """The k=64 cap lift: admitted block_s under the lane-tiled budget vs
-    the old full-k budget, plus interpret-mode timings at both widths."""
-    block_b, n = 8, 8
-    plan64 = SketchPlan(HashSpec(family="cyclic", n=n, L=32),
-                        (("sig", MinHashSpec(k=64)),))
-    admitted = _resolve_block_s(plan64, 1 << 20, block_b, 4096)
-    old_cap = _budget_cap(64, block_b, n)        # full-(bb,bs,k) tile budget
-    assert admitted > old_cap, (admitted, old_cap)
-
-    key = jax.random.PRNGKey(1)
-    kx, ka, kb = jax.random.split(key, 3)
-    h1v = jax.random.bits(kx, (B, S), dtype=jnp.uint32)
-    nw = jnp.full((B,), S - n + 1, jnp.int32)
-    rows = []
-    for k, bs, note in (
-            (64, min(admitted, S),
-             f"block_s={admitted} admitted (full-k budget capped at "
-             f"{old_cap}); lane_tile={_MINHASH_LANE_TILE}"),
-            (16, min(admitted, S),
-             "single lane chunk == pre-lane-tiling kernel (no regression)")):
-        a = jax.random.bits(ka, (k,), dtype=jnp.uint32) | np.uint32(1)
-        b = jax.random.bits(kb, (k,), dtype=jnp.uint32)
-        plan = SketchPlan(HashSpec(family="cyclic", n=n, L=32),
-                          (("sig", MinHashSpec(k=k)),))
-        run = lambda plan=plan, a=a, b=b, bs=bs: sketch_plan_fused(
-            h1v, None, nw, {"sig": {"a": a, "b": b}}, plan=plan,
-            block_b=block_b, block_s=bs, interpret=True)["sig"]
-        want = api.run(plan, h1v, n_windows=nw,
-                       operands={"sig": {"a": a, "b": b}}, impl="ref")["sig"]
-        np.testing.assert_array_equal(np.asarray(run()), np.asarray(want))
-        t = _timeit(lambda: jax.block_until_ready(run()), reps=2)
-        wins = B * (S - n + 1)
-        rows.append({"name": f"minhash_remix_lane_tiled_k{k}_bs{bs}",
-                     "us_per_call": t * 1e6,
-                     "derived": f"{wins / t / 1e6:.2f} Mwin/s interp; {note}"})
-    return rows
-
-
 def run(n_docs: int = 512, sign_B: int = 256, sign_S: int = 2048,
         scale: float = 1.0):
     """``scale`` (run.py passes REPRO_BENCH_CHARS / 4.3M) shrinks the
@@ -182,8 +135,7 @@ def run(n_docs: int = 512, sign_B: int = 256, sign_S: int = 2048,
     # docs to engage — a smaller smoke corpus would measure the fallback
     n_docs = max(256, int(n_docs * scale))
     sign_B = max(128, int(sign_B * scale))
-    return (_sign_sweep(sign_B, sign_S) + _dedup_rows(n_docs)
-            + _remix_rows())
+    return _sign_sweep(sign_B, sign_S) + _dedup_rows(n_docs)
 
 
 if __name__ == "__main__":

@@ -21,6 +21,9 @@ import numpy as np
 
 from repro.core import HyperLogLog, make_family
 from repro.data.corpus import bench_corpus
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N = 15
 corpus = bench_corpus(4_300_000)

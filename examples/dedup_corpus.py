@@ -9,6 +9,9 @@ import numpy as np
 
 from repro.data.corpus import CorpusSpec, documents
 from repro.data.dedup import DedupConfig, MinHashDeduper
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 spec = CorpusSpec(n_docs=500, dup_rate=0.25, mutate_frac=0.015, seed=42,
                   vocab=8192)

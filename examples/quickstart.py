@@ -11,6 +11,9 @@ from repro.core import independence as ind
 from repro.kernels import api
 from repro.kernels.plan import (CountMinSpec, HashSpec, HLLSpec, MinHashSpec,
                                 SketchPlan)
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 key = jax.random.PRNGKey(0)
 text = b"recursive n-gram hashing is pairwise independent, at best"

@@ -9,6 +9,9 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.nn import lm
 from repro.serve.engine import SamplerConfig, ServeEngine
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 cfg = get_config("paper-tiny").smoke()
 params, _ = lm.init(jax.random.PRNGKey(0), cfg)

@@ -16,6 +16,7 @@ from repro.data.pipeline import PipelineConfig
 from repro.train.fault import FailureInjector
 from repro.train.loop import LoopConfig, train
 from repro.train.optim import Schedule
+from repro.compile_cache import enable_compile_cache
 
 TINY = ModelConfig(
     name="demo-6m", n_layers=4, d_model=256, vocab=8192, n_heads=4,
@@ -25,6 +26,7 @@ TINY = ModelConfig(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="demo", choices=["demo", "100m"])
     ap.add_argument("--steps", type=int, default=120)

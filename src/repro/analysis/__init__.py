@@ -2,7 +2,7 @@
 Theorem-1/2 discard checker, and the repo-wide lint.
 
 - jaxpr.py     the shared jaxpr/HLO walker: primitive & collective census
-               (recursing through pjit/shard_map/scan/pallas bodies),
+               (recursing through jit/shard_map/scan/pallas bodies),
                donation/aliasing verification from lowered text, x64-leak &
                dtype-promotion detection, per-pallas_call VMEM estimates
 - contracts.py @kernel_contract declarations next to every entry point +

@@ -18,8 +18,8 @@ layers (``data/``, ``serve/``, ``kernels/decode.py``) with two rules:
   own shifts (probe word index ``>> 5``, HLL rank split) use constants or
   unrelated widths and never match.
 * ``DS2`` — the known probe-derivation entry points
-  (``ref.bloom_probe_hits``, ``sessions._bloom_add_rows``,
-  ``decode._probe_hits_tile``) must receive a *masked* hash argument: the
+  (``ref.bloom_probe_hits``, ``sessions._bloom_add_rows``) must receive a
+  *masked* hash argument: the
   argument expression (or the local name it was assigned from, tracked to a
   fixpoint inside the enclosing function) must route through ``hash_mask``.
 
@@ -56,7 +56,6 @@ SCOPE = ("src/repro/data", "src/repro/serve", "src/repro/kernels/decode.py")
 PROBE_CALLEES: Dict[str, int] = {
     "bloom_probe_hits": 0,      # ref.py — the probe oracle
     "_bloom_add_rows": 1,       # serve/sessions.py — filter insert
-    "_probe_hits_tile": 0,      # kernels/decode.py — the fused probe
 }
 
 # jaxpr primitives a raw (pre-mask) window hash may legitimately feed: the
@@ -69,7 +68,7 @@ ALLOWED_RAW_CONSUMERS = frozenset({
     # call-like region boundaries: passing a raw hash *into* a sub-region is
     # plumbing, not a probe — each region is analyzed independently (a
     # discard site inside the callee re-marks its own raw operand there)
-    "pjit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
+    "jit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
     "remat", "checkpoint", "scan", "while", "cond", "shard_map",
     "pallas_call",
 })

@@ -9,7 +9,7 @@ over nine test files, each with its own copy of the recursion into nested
 jaxprs. This module is the one walker they all share:
 
 * :func:`count_primitive` / :func:`primitive_census` — primitive counts,
-  recursing through every nested jaxpr (pjit bodies, ``shard_map`` regions,
+  recursing through every nested jaxpr (jit bodies, ``shard_map`` regions,
   scan/while bodies, custom calls, the pallas kernel jaxpr itself);
 * :func:`collective_census` / :func:`assert_no_collectives` — the SPMD
   primitives (``pmax``/``psum``/``all_gather``/...) the serving plane must
@@ -77,7 +77,7 @@ def as_jaxpr(obj):
 
 
 def _sub_jaxprs(eqn):
-    """Every nested jaxpr an equation carries (pjit/scan/while bodies,
+    """Every nested jaxpr an equation carries (jit/scan/while bodies,
     shard_map regions, custom-call and pallas kernel jaxprs)."""
     for v in eqn.params.values():
         for u in (v if isinstance(v, (list, tuple)) else [v]):

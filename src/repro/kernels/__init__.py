@@ -21,12 +21,15 @@ itself) and their data-plane consumers.
 - cyclic.py        rolling CYCLIC hash: direct-window + parallel-prefix modes
 - general.py       rolling GENERAL hash (clmul shift-reduce, trace-time consts)
 - sketch_fused.py  THE fused-kernel module: the plan kernel (family-generic
-                   tile hashes feeding every requested sketch epilogue, state
-                   reduced in VMEM scratch inside the grid loop with a
-                   lane-tiled MinHash remix; window hashes never round-trip
-                   HBM) plus the fused byte->fingerprint kernel (one-hot MXU
+                   tile hashes feeding every requested sketch epilogue;
+                   MinHash/HLL/narrow-CountMin state reduced in VMEM scratch
+                   inside the grid loop, Bloom probes and wide CountMin
+                   tables in XLA over the emitted hashes, same jit graph)
+                   plus the fused byte->fingerprint kernel (one-hot MXU
                    table lookup + window); cyclic_fused.py is a deprecation
                    shim over the latter
+- decode.py        decode-time epilogue: XLA candidate hashing + probes, one
+                   kernel pass masking the logits and packing the masks
 - bloom.py         Bloom membership probes (standalone decontamination scan)
 - hll.py           HyperLogLog register update (standalone telemetry)
 - ops.py           jit wrappers for the plain hash kernels + DEPRECATED

@@ -239,16 +239,21 @@ def bloom_probe_hits(h, words, k: int, log2_m: int) -> jnp.ndarray:
     sequence never touches the n-1 dependent bits."""
     h = h.astype(_U32)
     stride = (h * BLOOM_STRIDE) | np.uint32(1)
-    i = jnp.arange(k, dtype=_U32)
-    probes = (h[..., None] + i * stride[..., None]) & np.uint32((1 << log2_m) - 1)
-    word = (probes >> np.uint32(5)).astype(jnp.int32)
-    bit = probes & np.uint32(31)
-    if words.ndim == 1:                       # shared filter
-        got = words[word]
-    else:                                     # per-row filters
-        flat = word.reshape(word.shape[0], -1)
-        got = jnp.take_along_axis(words, flat, axis=1).reshape(word.shape)
-    return jnp.all(((got >> bit) & np.uint32(1)) == 1, axis=-1)
+    m_mask = np.uint32((1 << log2_m) - 1)
+    hit = jnp.ones(h.shape, jnp.bool_)
+    # one gather per probe: a single gather over all k probes at once takes
+    # the TPU compiler minutes at a 128k vocabulary, k gathers take seconds
+    for i in range(k):
+        probe = (h + np.uint32(i) * stride) & m_mask
+        word = (probe >> np.uint32(5)).astype(jnp.int32)
+        if words.ndim == 1:                   # shared filter
+            got = words[word]
+        else:                                 # per-row filters
+            got = jnp.take_along_axis(
+                words, word.reshape(word.shape[0], -1),
+                axis=1).reshape(word.shape)
+        hit = hit & (((got >> (probe & np.uint32(31))) & np.uint32(1)) == 1)
+    return hit
 
 
 def decode_masks_ref(logits, prefix, ready, bloom, h1, *, n: int, L: int,

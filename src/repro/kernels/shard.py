@@ -40,7 +40,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.contracts import kernel_contract
@@ -128,7 +128,7 @@ def sharded_execute(plan: SketchPlan, mesh: Mesh, ref_path: bool, tile,
         local, mesh=mesh,
         in_specs=(row, row if xb is not None else None, row,
                   row if ws is not None else None, op_specs),
-        out_specs=out_specs, check_rep=False)(x, xb, nw, ws, opd)
+        out_specs=out_specs, check_vma=False)(x, xb, nw, ws, opd)
     for name, (init, merge) in carry.items():
         out[name] = merge(out[name], init)
     return out
@@ -206,7 +206,7 @@ def rowwise(fn, mesh: Mesh, n_row: int):
     are sharded over the mesh axis, the remaining arguments are replicated,
     and every output leaf comes back row-sharded. Because ``fn`` is per-row
     by contract, no collective is needed (or emitted — the decode-plane
-    tests assert zero collective primitives in the jaxpr); ``check_rep`` is
+    tests assert zero collective primitives in the jaxpr); ``check_vma`` is
     off for the same reason the plan executor's is.
 
     This is the serving plane's scale-out primitive: a session pool's carry
@@ -224,7 +224,7 @@ def rowwise(fn, mesh: Mesh, n_row: int):
                              f"{len(args)} argument(s)")
         in_specs = tuple(row if i < n_row else rep for i in range(len(args)))
         return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=row,
-                         check_rep=False)(*args)
+                         check_vma=False)(*args)
 
     return wrapped
 

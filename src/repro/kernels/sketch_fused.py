@@ -8,9 +8,9 @@ gather/scatter-max register chain, the Bloom scan re-reads it twice (two
 family draws). :func:`sketch_plan_fused` instead *reduces the hashes inside
 the grid loop*: the rolling hash of each tile is computed **once** and
 consumed immediately by every sketch epilogue the plan requests, and only
-the tiny sketch states (a ``(k,)`` signature row, an ``(m,)`` register
-file, a per-row hit count) ever leave the chip. Window hashes never
-round-trip HBM, even when one pass feeds MinHash + HLL + Bloom together.
+the small sketch states (a ``(k,)`` signature row, a register file, a
+CountMin table) leave the chip. Bloom probes and wide CountMin tables are
+the exception: they read the emitted window hashes in XLA (below).
 
 Design (the grid-carried scratch-accumulator idiom):
 
@@ -22,22 +22,21 @@ Design (the grid-carried scratch-accumulator idiom):
   shift-reduce against trace-time ``x^k mod p(x)`` constants from
   ``kernels/general.py`` (O(Ln), the paper's bound) — same grid, same
   epilogues, so plans are family-generic.
-* Each sketch's state lives in its own VMEM ``scratch_shapes`` buffer. TPU
-  grids execute sequentially with the last grid dimension innermost, so for
-  each batch block the sequence blocks ``j = 0..gs-1`` arrive in order: the
-  epilogue initialises the scratch at ``j == 0``, folds its tile's
-  contribution with the reduction's own combine (min for MinHash, max for
-  HLL, add for Bloom hit counts), and flushes scratch to its output on the
-  final block. The HLL register file reduces across the *whole* grid (batch
-  blocks too), so it initialises at the very first grid step and flushes at
-  the very last.
+* Each in-kernel sketch's state lives in its own VMEM ``scratch_shapes``
+  buffer. TPU grids execute sequentially with the last grid dimension
+  innermost, so for each batch block the sequence blocks ``j = 0..gs-1``
+  arrive in order: the MinHash epilogue initialises its scratch at
+  ``j == 0``, folds each tile with min, and flushes on the final block.
+  The HLL registers and the CountMin table reduce across the *whole* grid
+  (batch blocks too), so they initialise at the very first grid step and
+  flush at the very last.
 * Masking of padded windows: callers pass per-row valid-window counts
   (``n_windows``); a window whose global index falls at or beyond that count
   is *excluded from the reduction outright* — MinHash replaces its remixed
   values with the ``0xFFFFFFFF`` sentinel AFTER the affine step (pre-remix
   sentinel substitution would let ``a*SENTINEL+b`` undercut the true min),
-  HLL and Bloom zero the window's contribution (rank 0 / hit 0). A padded
-  row's sketch is therefore bit-identical to the unpadded document's and
+  HLL, CountMin and Bloom zero the window's contribution. A padded row's
+  sketch is therefore bit-identical to the unpadded document's and
   independent of bucket size. Rows padded up to the batch tile get
   ``n_windows = 0`` and are sliced off on return.
 * The Theorem-1 discard is fused too: ``HashSpec.hash_mask`` keeps the low
@@ -45,23 +44,31 @@ Design (the grid-carried scratch-accumulator idiom):
   outside a vector register. GENERAL keeps all L bits (pairwise independent
   as-is).
 
-VMEM budgets: the MinHash epilogue is *lane-tiled* — its live remix tile is
-``(block_b, block_s, min(k, lane_tile))``, independent of the signature
-width, so ``block_s`` no longer shrinks at k=64 (pass 1 reduces each lane
-chunk's candidate minima, pass 2 folds them into the ``(block_b, k)``
-scratch). The HLL epilogue still materialises a ``(block_b*block_s, m)``
-one-hot tile, so its cap scales with ``m``; both budgets are enforced by
-``_resolve_block_s`` against a ~4 MB tile target.
+What the TPU compiler (Mosaic) accepts shapes every epilogue:
 
-The CountMin epilogue is two-mode, the decision recorded statically on
-:class:`~repro.kernels.plan.CountMinSpec` (``use_in_kernel``): tables up to
-``2^in_kernel_max_log2_width`` columns accumulate depth-major one-hot
-partial sums in a ``(depth, width)`` VMEM scratch (the one-hot walk is
-row-chunked to ``_CMS_ROW_TILE`` so its live tile never exceeds ~4 MB);
-wider tables — XLA's scatter-add handles the production 2^16 better than
-any VMEM-resident histogram — make the kernel emit its masked window-hash
-tiles instead, and ``cms_reduce`` scatter-adds them *inside the same jit
-graph* (the one plan output that round-trips hashes through HBM).
+* MinHash takes its remix constants as SMEM scalars, so each of the k
+  signature lanes is one dense ``(block_b, block_s)`` pass reduced over the
+  window lanes. Mosaic has no unsigned min, so the minima are taken in the
+  order-preserving signed view (flip the sign bit, bitcast to int32).
+* HLL and in-kernel CountMin keep their buckets in a lane-aligned
+  ``(rows, 128)`` layout (bucket ``i`` at ``(i // 128, i % 128)``) and
+  histogram each tile row on the MXU: the contraction over the window
+  lanes of a row one-hot against a lane one-hot counts the windows per
+  bucket exactly (0/1 products, integer sums). CountMin weights the row
+  one-hot by validity and adds; HLL stacks one row one-hot per rank value
+  and keeps, per register, the largest rank with a nonzero count. Live
+  tiles are ``(rows * ranks, block_s)`` and ``(128, block_s)``,
+  independent of the batch tile; ``_resolve_block_s`` caps ``block_s`` so
+  the HLL stack stays near 4 MB.
+* Bloom filters are probed by a gather, and Mosaic has no gather from a
+  VMEM filter; CountMin tables wider than ``2^in_kernel_max_log2_width``
+  columns are faster as XLA's scatter-add than as a VMEM histogram. For
+  both, the kernel emits its masked window-hash tiles (and the second
+  stream's, for Bloom's probe stride) and the probe or scatter-add runs
+  *inside the same jit graph* — still one ``pallas_call`` per plan.
+* Carries: the MinHash carry seeds its scratch at ``j == 0``; the HLL,
+  CountMin and Bloom carries merge with their own operator (max, add, add)
+  in the XLA epilogue, which is exact on integers.
 
 The legacy single-sketch entry points (``cyclic_minhash_fused`` /
 ``cyclic_hll_fused`` / ``cyclic_bloom_fused``) are thin wrappers that build
@@ -91,22 +98,13 @@ from repro.kernels.plan import (BloomSpec, CountMinSpec, HashSpec, HLLSpec,
 
 _U32 = jnp.uint32
 _SENTINEL = np.uint32(0xFFFFFFFF)
+# flipping the sign bit maps uint32 order onto int32 order: Mosaic reduces
+# and compares signed integers only, so uint32 minima go through this view
+_SIGN = np.uint32(0x80000000)
+_LANES = 128
 
-# MinHash remix lane-tile width: the kernel's live remix tile is
-# (block_b, block_s, min(k, _MINHASH_LANE_TILE)) regardless of k, so
-# block_s no longer shrinks with the signature width. 16 lanes keep k<=16
-# plans on the exact pre-lane-tiling computation (one chunk).
-_MINHASH_LANE_TILE = 16
-
-# CountMin one-hot row-tile: the in-kernel histogram walks the tile's
-# flattened windows in chunks of this many rows, so its live one-hot tile
-# is (_CMS_ROW_TILE, width) regardless of block_b/block_s — 4 MB at the
-# spec's default in-kernel ceiling of 2^12 columns.
-_CMS_ROW_TILE = 256
-
-# per-sketch default sequence tiles (a multi-sketch plan takes the min);
-# the lane-tiled remix admits a 1024-wide MinHash tile even at k=64
-_BLOCK_S_DEFAULTS = {MinHashSpec: 1024, HLLSpec: 256, BloomSpec: 1024,
+# per-sketch default sequence tiles (a multi-sketch plan takes the min)
+_BLOCK_S_DEFAULTS = {MinHashSpec: 1024, HLLSpec: 512, BloomSpec: 1024,
                      CountMinSpec: 512}
 
 
@@ -141,134 +139,133 @@ def _valid_mask(nw_col, ws_col, j, shape):
     return ok
 
 
+def _signed(u):
+    """uint32 -> int32 with the same order (sign-bit flip, then bitcast)."""
+    return jax.lax.bitcast_convert_type(u ^ _SIGN, jnp.int32)
+
+
+def _unsigned(s):
+    """Inverse of :func:`_signed`."""
+    return jax.lax.bitcast_convert_type(s, _U32) ^ _SIGN
+
+
+def _hist_rows(n_buckets: int) -> int:
+    """Sublane rows of the lane-aligned (rows, 128) bucket layout: bucket i
+    lives at (i // 128, i % 128); rows are padded to the 8-row tile."""
+    return -(-max(1, n_buckets // _LANES) // 8) * 8
+
+
+def _nt_dot(a, b):
+    """(M, S) x (N, S) -> (M, N): contract the window (lane) axis of both
+    one-hot factors on the MXU. Exact: 0/1 products, integer sums < 2^24."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _lo_onehot(idx_row):
+    """(1, S) bucket ids -> (128, S) f32 one-hot of ``idx % 128``."""
+    return ((idx_row & (_LANES - 1)) == jax.lax.broadcasted_iota(
+        jnp.int32, (_LANES, idx_row.shape[1]), 0)).astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Per-sketch tile epilogues (shared by every plan containing the sketch)
 # ---------------------------------------------------------------------------
 
 
 def _minhash_tile(h, valid, a_ref, b_ref, o_ref, acc_ref, j, init_ref=None):
+    """Per-row running minima of the k affine remixes ``a[i]*h + b[i]``.
+
+    The remix constants are SMEM scalars, so each signature lane is one
+    dense (block_b, block_s) pass reduced over the window lanes; the minima
+    live in the order-preserving signed view (Mosaic has no unsigned min).
+    Invalid (padded) windows are excluded from the min entirely (post-remix
+    sentinel substitution), so a padded row's signature is bit-identical
+    to the unpadded one."""
     @pl.when(j == 0)
     def _init():
         # carry-in scratch init: a chunked/streaming caller seeds the
         # accumulator with its running state instead of the identity, so
         # the grid reduction continues the stream's min exactly
-        acc_ref[...] = (jnp.full_like(acc_ref, _SENTINEL)
-                        if init_ref is None else init_ref[...])
+        acc_ref[...] = _signed(jnp.full(acc_ref.shape, _SENTINEL, _U32)
+                               if init_ref is None else init_ref[...])
 
-    # lane-tiled two-pass remix: pass 1 walks the k signature lanes in
-    # _MINHASH_LANE_TILE-wide chunks — each chunk remixes the tile's hashes
-    # for just those lanes and reduces the window axis to per-lane candidate
-    # minima — so the live remix tile is (block_b, block_s, lane_tile), not
-    # (block_b, block_s, k); pass 2 folds the (block_b, k) candidates into
-    # the scratch accumulator. Invalid (padded) windows are excluded from
-    # the min entirely (post-remix sentinel substitution), so the signature
-    # of a padded row is bit-identical to the unpadded one. Min is
-    # associative/commutative on uint32, so the chunked reduction is
-    # bit-identical to the monolithic one; for k <= lane_tile it IS the
-    # monolithic one (single chunk).
-    a, b = a_ref[...], b_ref[...]
-    cand = []
-    for s in range(0, a.shape[0], _MINHASH_LANE_TILE):
-        ac = a[s : s + _MINHASH_LANE_TILE]
-        bc = b[s : s + _MINHASH_LANE_TILE]
-        mixed = (ac[None, None, :] * h[:, :, None]
-                 + bc[None, None, :])                   # (bb, bs, lane_tile)
-        mixed = jnp.where(valid[:, :, None], mixed, _SENTINEL)
-        cand.append(jnp.min(mixed, axis=1))             # pass 1: per-lane min
-    acc_ref[...] = jnp.minimum(acc_ref[...],            # pass 2: fold lanes
-                               jnp.concatenate(cand, axis=-1))
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
-
-
-def _hll_tile(h, valid, b: int, rank_bits: int, o_ref, acc_ref, bi, j,
-              init_ref=None):
-    @pl.when((bi == 0) & (j == 0))
-    def _init():
-        acc_ref[...] = (jnp.zeros_like(acc_ref) if init_ref is None
-                        else init_ref[...])
-
-    hf = h.reshape(-1)
-    vf = valid.reshape(-1)
-    m = 1 << b
-    idx = (hf & np.uint32(m - 1)).astype(jnp.int32)
-    rest = hf >> np.uint32(b)
-    isolated = rest & (~rest + np.uint32(1))
-    tz = jax.lax.population_count(isolated - np.uint32(1))
-    rank = (jnp.minimum(tz, np.uint32(rank_bits)) + 1).astype(jnp.int32)
-    rank = jnp.where(vf, rank, 0)                       # rank 0 never wins
-    onehot = (idx[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (idx.shape[0], m), 1))
-    partial = jnp.where(onehot, rank[:, None], 0).max(axis=0)
-    acc_ref[...] = jnp.maximum(acc_ref[...], partial)
-
-    @pl.when((bi == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
-    def _flush():
-        o_ref[...] = acc_ref[...]
-
-
-def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, o_ref, acc_ref, bi, j,
-              init_ref=None):
-    """Depth-major in-kernel CountMin histogram: row d's partial counts are
-    a one-hot accumulation of the tile's remixed column indices, chunked
-    into ``_CMS_ROW_TILE``-row one-hot tiles so the live VMEM tile is
-    (row_tile, width) regardless of block_b/block_s. Counts are additive,
-    so the (depth, width) scratch reduces across the WHOLE grid (batch
-    blocks too, like HLL): init at the very first grid step (from the
-    carry-in table when one is given), flush at the very last. Invalid
-    (padded) windows add 0."""
-    @pl.when((bi == 0) & (j == 0))
-    def _init():
-        acc_ref[...] = (jnp.zeros_like(acc_ref) if init_ref is None
-                        else init_ref[...])
-
-    hf = h.reshape(-1)
-    vf = valid.reshape(-1).astype(jnp.int32)
-    width = 1 << log2_width
-    shift = np.uint32(32 - log2_width)
-    a, b = a_ref[...], b_ref[...]
-    for d in range(a.shape[0]):
-        cols = ((a[d] * hf + b[d]) >> shift).astype(jnp.int32)
-        partial = jnp.zeros((width,), jnp.int32)
-        for s in range(0, cols.shape[0], _CMS_ROW_TILE):
-            cc = cols[s : s + _CMS_ROW_TILE]
-            onehot = (cc[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (cc.shape[0], width), 1))
-            partial = partial + jnp.sum(
-                jnp.where(onehot, vf[s : s + _CMS_ROW_TILE, None], 0), axis=0)
-        acc_ref[d, :] = acc_ref[d, :] + partial
-
-    @pl.when((bi == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
-    def _flush():
-        o_ref[...] = acc_ref[...]
-
-
-def _bloom_tile(h, hb, valid, bits_ref, k: int, log2_m: int, o_ref, acc_ref, j,
-                init_ref=None):
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = (jnp.zeros_like(acc_ref) if init_ref is None
-                        else init_ref[...])
-
-    hb = hb | np.uint32(1)                              # odd probe stride
-    bits = bits_ref[...]
-    m_mask = np.uint32((1 << log2_m) - 1)
-    hit = jnp.ones(h.shape, dtype=jnp.bool_)
+    k = acc_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+    cand = acc_ref[...]
     for i in range(k):
-        probe = (h + np.uint32(i) * hb) & m_mask
-        word = (probe >> np.uint32(5)).astype(jnp.int32)
-        bit = probe & np.uint32(31)
-        got = jnp.take(bits, word.reshape(-1), axis=0).reshape(word.shape)
-        hit = hit & (((got >> bit) & np.uint32(1)) == 1)
-    cnt = jnp.sum(jnp.where(valid, hit, False).astype(jnp.int32), axis=1,
-                  keepdims=True)
-    acc_ref[...] = acc_ref[...] + cnt
+        mixed = jnp.where(valid, a_ref[i] * h + b_ref[i], _SENTINEL)
+        col = jnp.min(_signed(mixed), axis=1, keepdims=True)
+        cand = jnp.where(lane == i, jnp.minimum(cand, col), cand)
+    acc_ref[...] = cand
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[...] = acc_ref[...]
+        o_ref[...] = _unsigned(acc_ref[...])
+
+
+def _hll_tile(h, valid, b: int, rank_bits: int, acc_ref, bi, j):
+    """HLL register maxima in the lane-aligned (rows, 128) layout.
+
+    Per tile row, one MXU contraction over the window lanes counts the
+    windows of every (rank, register) pair: the left factor stacks one
+    register-row one-hot per rank value, the right factor is the register
+    lane one-hot. A register's new value is the largest rank with a
+    nonzero count. The registers reduce across the WHOLE grid (batch
+    blocks too); the flush writes them at the last grid step and the
+    wrapper merges any carry-in with max, outside the kernel."""
+    @pl.when((bi == 0) & (j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    rows = acc_ref.shape[0]
+    n_rank = rank_bits + 1
+    idx = (h & np.uint32((1 << b) - 1)).astype(jnp.int32)
+    rest = h >> np.uint32(b)
+    isolated = rest & (~rest + np.uint32(1))
+    tz = jax.lax.population_count(isolated - np.uint32(1)).astype(jnp.int32)
+    rank = jnp.minimum(tz, np.int32(rank_bits)) + 1
+    rank = jnp.where(valid, rank, 0)                    # rank 0 never wins
+    S = h.shape[1]
+    stack = jax.lax.broadcasted_iota(jnp.int32, (n_rank * rows, S), 0)
+    reg_row, rank_of = stack % rows, stack // rows + 1
+    rank_col = (jax.lax.broadcasted_iota(jnp.int32, (n_rank * rows, _LANES), 0)
+                // rows + 1)
+    acc = acc_ref[...]
+    for r in range(h.shape[0]):
+        ir, rr = idx[r : r + 1], rank[r : r + 1]
+        left = (((ir >> 7) == reg_row) & (rr == rank_of)).astype(jnp.float32)
+        seen = jnp.where(_nt_dot(left, _lo_onehot(ir)) > 0, rank_col, 0)
+        acc = jnp.maximum(acc, jnp.max(seen.reshape(n_rank, rows, _LANES),
+                                       axis=0))
+    acc_ref[...] = acc
+
+
+def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, acc_ref, bi, j):
+    """Depth-major in-kernel CountMin histogram in the lane-aligned
+    (depth*rows, 128) layout: per tile row and table row d, one MXU
+    contraction of the column's row one-hot (weighted by validity) against
+    its lane one-hot adds the tile's counts. Counts are additive, so the
+    table reduces across the WHOLE grid; any carry-in table is added by the
+    wrapper, outside the kernel. Invalid (padded) windows add 0."""
+    @pl.when((bi == 0) & (j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    depth = a_ref.shape[0]
+    rows = acc_ref.shape[0] // depth
+    shift = np.uint32(32 - log2_width)
+    vf = valid.astype(jnp.float32)
+    reg_row = jax.lax.broadcasted_iota(jnp.int32, (rows, h.shape[1]), 0)
+    for d in range(depth):
+        cols = ((a_ref[d] * h + b_ref[d]) >> shift).astype(jnp.int32)
+        part = jnp.zeros((rows, _LANES), jnp.float32)
+        for r in range(h.shape[0]):
+            cr = cols[r : r + 1]
+            left = ((cr >> 7) == reg_row).astype(jnp.float32) * vf[r : r + 1]
+            part = part + _nt_dot(left, _lo_onehot(cr))
+        sl = slice(d * rows, (d + 1) * rows)
+        acc_ref[sl, :] = acc_ref[sl, :] + part.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -276,37 +273,37 @@ def _bloom_tile(h, hb, valid, bits_ref, k: int, log2_m: int, o_ref, acc_ref, j,
 # ---------------------------------------------------------------------------
 
 
-def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
-                 init_flags):
-    hs = plan.hash
-    specs = plan.sketches
-    # per-sketch kernel inputs: the spec's declared operands, then (when the
-    # caller passed a carry) its `init` state — init_flags is the static
-    # presence vector (CMS-scatter carries fold in the XLA epilogue instead)
-    opcounts = [len(spec.operand_names) + int(f)
-                for (_, spec), f in zip(specs, init_flags)]
-    needs_b = plan.needs_second_stream
-    n_in = 2 + (2 if needs_b else 0) + 1 + int(has_ws) + sum(opcounts)
-    ns = len(specs)
-    in_refs = refs[:n_in]
-    out_refs = refs[n_in : n_in + ns]
-    acc_refs = refs[n_in + ns :]
+def _in_kernel(spec) -> bool:
+    """Sketches reduced inside the kernel; the others (Bloom, wide CountMin)
+    consume the emitted window hashes in the XLA epilogue."""
+    return isinstance(spec, (MinHashSpec, HLLSpec)) or (
+        isinstance(spec, CountMinSpec) and spec.use_in_kernel)
 
-    pos = 2
-    x_ref, xh_ref = in_refs[0], in_refs[1]
+
+def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
+                 has_init: bool, emit_h: bool):
+    """Refs: x, halo[, xb, xb halo], n_windows[, w_start], then per
+    in-kernel sketch its operands (MinHash a, b[, init]; CountMin a, b);
+    outputs: one per in-kernel sketch, then the emitted h[, hb] tiles;
+    scratch: one accumulator per in-kernel sketch."""
+    hs = plan.hash
+    specs = [spec for _, spec in plan.sketches if _in_kernel(spec)]
+    needs_b = plan.needs_second_stream
+    it = iter(refs)
+    x_ref, xh_ref = next(it), next(it)
     if needs_b:
-        xb_ref, xbh_ref = in_refs[2], in_refs[3]
-        pos = 4
-    nw_ref = in_refs[pos]
-    pos += 1
-    ws_ref = None
-    if has_ws:
-        ws_ref = in_refs[pos]
-        pos += 1
+        xb_ref, xbh_ref = next(it), next(it)
+    nw_ref = next(it)
+    ws_ref = next(it) if has_ws else None
     op_refs = []
-    for c in opcounts:
-        op_refs.append(in_refs[pos : pos + c])
-        pos += c
+    for spec in specs:
+        n_ops = {MinHashSpec: 2 + int(has_init), HLLSpec: 0,
+                 CountMinSpec: 2}[type(spec)]
+        op_refs.append([next(it) for _ in range(n_ops)])
+    out_refs = [next(it) for _ in specs]
+    h_ref = next(it) if emit_h else None
+    hb_ref = next(it) if needs_b else None
+    acc_refs = [next(it) for _ in specs]
 
     bi, j = pl.program_id(0), pl.program_id(1)
     x = x_ref[...]
@@ -315,46 +312,45 @@ def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
     h = _tile_window_hashes(x, xh_ref[...], hs=hs, block_s=block_s) & mask
     valid = _valid_mask(nw_ref[...], ws_ref[...] if has_ws else None, j,
                         x.shape)
-    hb = None
+    if emit_h:
+        h_ref[...] = h
     if needs_b:
-        hb = _tile_window_hashes(xb_ref[...], xbh_ref[...], hs=hs,
-                                 block_s=block_s) & mask
+        hb_ref[...] = _tile_window_hashes(xb_ref[...], xbh_ref[...], hs=hs,
+                                          block_s=block_s) & mask
 
-    for (name, spec), o_ref, acc_ref, oprs, has_init in zip(
-            specs, out_refs, acc_refs, op_refs, init_flags):
-        init_ref = oprs[-1] if has_init else None
+    for spec, o_ref, acc_ref, oprs in zip(specs, out_refs, acc_refs, op_refs):
         if isinstance(spec, MinHashSpec):
             _minhash_tile(h, valid, oprs[0], oprs[1], o_ref, acc_ref, j,
-                          init_ref)
-        elif isinstance(spec, HLLSpec):
-            _hll_tile(h, valid, spec.b, spec.resolve_rank_bits(hs), o_ref,
-                      acc_ref, bi, j, init_ref)
-        elif isinstance(spec, CountMinSpec):
-            if spec.use_in_kernel:
-                _cms_tile(h, valid, oprs[0], oprs[1], spec.log2_width,
-                          o_ref, acc_ref, bi, j, init_ref)
-            else:
-                # table too wide for VMEM scratch: emit the tile's masked
-                # window hashes; the XLA scatter-add epilogue (same jit
-                # graph, see sketch_plan_fused) builds the histogram
-                o_ref[...] = h
+                          oprs[2] if has_init else None)
+            continue
+        if isinstance(spec, HLLSpec):
+            _hll_tile(h, valid, spec.b, spec.resolve_rank_bits(hs), acc_ref,
+                      bi, j)
         else:
-            _bloom_tile(h, hb, valid, oprs[0], spec.k, spec.log2_m, o_ref,
-                        acc_ref, j, init_ref)
+            _cms_tile(h, valid, oprs[0], oprs[1], spec.log2_width, acc_ref,
+                      bi, j)
+
+        @pl.when((bi == pl.num_programs(0) - 1)
+                 & (j == pl.num_programs(1) - 1))
+        def _flush(o_ref=o_ref, acc_ref=acc_ref):
+            o_ref[...] = acc_ref[...]
 
 
-def _budget_cap(lanes: int, block_b: int, n: int) -> int:
-    """Largest pow2 block_s keeping a (block_b, block_s, lanes) int32 tile
-    within ~4 MB of VMEM (the halo still sets a floor)."""
-    cap = max(32, (4 << 20) // (4 * lanes * block_b))
+def _budget_cap(rows: int, n: int) -> int:
+    """Largest pow2 block_s keeping a (rows, block_s) 4-byte tile within
+    ~4 MB of VMEM (the halo still sets a floor)."""
+    cap = max(32, (4 << 20) // (4 * rows))
     cap = 1 << int(np.floor(np.log2(cap)))
     if n > 1 and n - 1 > cap:
         cap = 1 << int(np.ceil(np.log2(n - 1)))
     return cap
 
 
-def _resolve_block_s(plan: SketchPlan, S: int, block_b: int, block_s):
-    """Sequence-tile width honouring every requested sketch's VMEM budget."""
+def _resolve_block_s(plan: SketchPlan, S: int, block_s):
+    """Sequence-tile width honouring every requested sketch's VMEM budget,
+    rounded up to whole 128-lane vregs: Mosaic refuses blocks whose last
+    dimension is not a multiple of 128, and the interpreter runs the same
+    tiles the chip compiles."""
     if block_s is None:
         block_s = min(_BLOCK_S_DEFAULTS[type(spec)]
                       for _, spec in plan.sketches)
@@ -362,15 +358,11 @@ def _resolve_block_s(plan: SketchPlan, S: int, block_b: int, block_s):
     n = plan.hash.n
     for _, spec in plan.sketches:
         if isinstance(spec, HLLSpec):
-            # the (block_b*block_s, m) one-hot reduction tile: at the
-            # production m=4096 the default tiles would need 32 MB, which no
-            # core has — shrink block_s
-            block_s = min(block_s, _budget_cap(1 << spec.b, block_b, n))
-        elif isinstance(spec, MinHashSpec):
-            # the lane-tiled remix budgets min(k, lane_tile) lanes, not k:
-            # block_s no longer shrinks as the signature widens to k=64
-            lanes = min(spec.k, _MINHASH_LANE_TILE)
-            block_s = min(block_s, _budget_cap(lanes, block_b, n))
+            # the HLL walk's (ranks * rows, block_s) stacked one-hot
+            stacked = ((spec.resolve_rank_bits(plan.hash) + 1)
+                       * _hist_rows(1 << spec.b))
+            block_s = min(block_s, _budget_cap(stacked, n))
+    block_s = -(-block_s // _LANES) * _LANES
     if n - 1 > block_s:
         raise ValueError(f"halo n-1={n-1} exceeds block_s={block_s}")
     return block_s
@@ -392,17 +384,19 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     scratch up to the spec's ``in_kernel_max_log2_width``; wider tables are
     scatter-added from kernel-emitted hashes in the same jit graph).
 
-    A sketch's optional ``init`` operand (its ``state_struct`` shape) seeds
-    that sketch's scratch accumulator at the first grid step instead of the
-    identity — the reduction then *continues* a running state, which is what
-    makes the chunked streaming executor bit-exact. ``w_start`` (B,) int32
+    A sketch's optional ``init`` operand (its ``state_struct`` shape)
+    continues a running state instead of starting from the identity: the
+    MinHash carry seeds the kernel's scratch at the first grid step, the
+    corpus-level carries (HLL, CountMin) and the Bloom counts merge with
+    their own operator in the XLA epilogue — which is what makes the
+    chunked streaming executor bit-exact. ``w_start`` (B,) int32
     optionally sets the per-row FIRST valid window (the mask becomes the
     range ``[w_start, n_windows)``), masking windows that would span a
     stream chunk's zero-filled pre-history.
     """
     assert h1v.ndim == 2 and n_windows.shape == (h1v.shape[0],)
     B, S = h1v.shape
-    block_s = _resolve_block_s(plan, S, block_b, block_s)
+    block_s = _resolve_block_s(plan, S, block_s)
     Bp = -(-B // block_b) * block_b
     Sp = -(-S // block_s) * block_s
     x = jnp.pad(h1v.astype(_U32), ((0, Bp - B), (0, Sp - S)))
@@ -410,6 +404,7 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     grid = (Bp // block_b, Sp // block_s)
     nsb = grid[1]
     has_ws = w_start is not None
+    operands = operands or {}
 
     tile = pl.BlockSpec((block_b, block_s), lambda bi, j: (bi, j),
                         memory_space=pltpu.VMEM)
@@ -418,8 +413,9 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
                         memory_space=pltpu.VMEM)
     row = lambda w: pl.BlockSpec((block_b, w), lambda bi, j: (bi, 0),
                                  memory_space=pltpu.VMEM)
-    flat = lambda w: pl.BlockSpec((w,), lambda bi, j: (0,),
-                                  memory_space=pltpu.VMEM)
+    whole = lambda shape: pl.BlockSpec(shape, lambda bi, j: (0, 0),
+                                       memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     in_specs, inputs = [tile, halo], [x, x]
     if plan.needs_second_stream:
@@ -437,105 +433,95 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
         in_specs.append(row(1))
         inputs.append(ws)
 
-    init_flags = []
+    # the MinHash carry rides into the kernel (row state, seeded at j == 0);
+    # every other carry merges in the XLA epilogue below
+    has_init = any("init" in operands.get(name, {})
+                   for name, spec in plan.sketches
+                   if isinstance(spec, MinHashSpec))
     out_specs, out_shapes, scratches = [], [], []
     for name, spec in plan.sketches:
-        ops_nm = operands.get(name, {}) if operands else {}
-        # the carry rides into the kernel for every reduction epilogue; the
-        # CMS scatter fallback folds it in its XLA epilogue below instead
-        has_init = "init" in ops_nm and not (
-            isinstance(spec, CountMinSpec) and not spec.use_in_kernel)
-        init_flags.append(has_init)
+        ops_nm = operands.get(name, {})
         if isinstance(spec, MinHashSpec):
-            in_specs += [flat(spec.k), flat(spec.k)]
+            in_specs += [smem, smem]
             inputs += [ops_nm["a"].astype(_U32), ops_nm["b"].astype(_U32)]
             if has_init:
+                init = ops_nm.get("init")
+                init = (jnp.full((B, spec.k), _SENTINEL, _U32) if init is None
+                        else init.astype(_U32))
                 in_specs.append(row(spec.k))
-                inputs.append(jnp.pad(ops_nm["init"].astype(_U32),
-                                      ((0, Bp - B), (0, 0))))
+                inputs.append(jnp.pad(init, ((0, Bp - B), (0, 0))))
             out_specs.append(row(spec.k))
             out_shapes.append(jax.ShapeDtypeStruct((Bp, spec.k), _U32))
-            scratches.append(pltpu.VMEM((block_b, spec.k), _U32))
+            scratches.append(pltpu.VMEM((block_b, spec.k), jnp.int32))
         elif isinstance(spec, HLLSpec):
-            m = 1 << spec.b
-            if has_init:
-                in_specs.append(flat(m))
-                inputs.append(ops_nm["init"].astype(jnp.int32))
-            out_specs.append(flat(m))
-            out_shapes.append(jax.ShapeDtypeStruct((m,), jnp.int32))
-            scratches.append(pltpu.VMEM((m,), jnp.int32))
-        elif isinstance(spec, CountMinSpec):
-            in_specs += [flat(spec.depth), flat(spec.depth)]
+            shape = (_hist_rows(1 << spec.b), _LANES)
+            out_specs.append(whole(shape))
+            out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.int32))
+            scratches.append(pltpu.VMEM(shape, jnp.int32))
+        elif _in_kernel(spec):                       # CountMin in VMEM
+            in_specs += [smem, smem]
             inputs += [ops_nm["a"].astype(_U32), ops_nm["b"].astype(_U32)]
-            if spec.use_in_kernel:
-                table_spec = pl.BlockSpec(
-                    (spec.depth, spec.width), lambda bi, j: (0, 0),
-                    memory_space=pltpu.VMEM)
-                if has_init:
-                    in_specs.append(table_spec)
-                    inputs.append(ops_nm["init"].astype(jnp.int32))
-                out_specs.append(table_spec)
-                out_shapes.append(
-                    jax.ShapeDtypeStruct((spec.depth, spec.width), jnp.int32))
-                scratches.append(pltpu.VMEM((spec.depth, spec.width),
-                                            jnp.int32))
-            else:
-                # scatter fallback: the kernel emits its masked window-hash
-                # tiles (the one sketch output that is NOT a reduction);
-                # the histogram is built by cms_reduce below, in the same
-                # jit graph. Scratch is a dummy — nothing accumulates.
-                out_specs.append(tile)
-                out_shapes.append(jax.ShapeDtypeStruct((Bp, Sp), _U32))
-                scratches.append(pltpu.VMEM((1, 1), jnp.int32))
-        else:
-            # full filter resident per grid step
-            in_specs.append(flat(spec.n_words))
-            inputs.append(ops_nm["bits"].astype(_U32))
-            if has_init:
-                in_specs.append(row(1))
-                inputs.append(jnp.pad(ops_nm["init"].astype(jnp.int32),
-                                      (0, Bp - B))[:, None])
-            out_specs.append(row(1))
-            out_shapes.append(jax.ShapeDtypeStruct((Bp, 1), jnp.int32))
-            scratches.append(pltpu.VMEM((block_b, 1), jnp.int32))
+            shape = (spec.depth * _hist_rows(spec.width), _LANES)
+            out_specs.append(whole(shape))
+            out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.int32))
+            scratches.append(pltpu.VMEM(shape, jnp.int32))
+    # Bloom probes and wide CountMin tables consume the tile's masked window
+    # hashes in XLA (Mosaic has no gather from a VMEM filter, and XLA's
+    # scatter-add beats a VMEM histogram at 2^16 columns): the kernel emits
+    # them, the one plan output that round-trips hashes through HBM
+    emit_h = not all(_in_kernel(spec) for _, spec in plan.sketches)
+    for emitted in (emit_h, plan.needs_second_stream):
+        if emitted:
+            out_specs.append(tile)
+            out_shapes.append(jax.ShapeDtypeStruct((Bp, Sp), _U32))
 
-    outs = pl.pallas_call(
+    outs = list(pl.pallas_call(
         functools.partial(_plan_kernel, plan=plan, block_s=block_s,
-                          has_ws=has_ws, init_flags=tuple(init_flags)),
+                          has_ws=has_ws, has_init=has_init, emit_h=emit_h),
         grid=grid,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shapes),
         scratch_shapes=scratches,
         interpret=interpret,
-    )(*inputs)
+    )(*inputs))
 
+    hb_out = outs.pop() if plan.needs_second_stream else None
+    h_out = outs.pop() if emit_h else None
+    if h_out is not None:
+        # validity re-derived from the padded n_windows exactly as
+        # in-kernel (padded rows have nw=0, out-of-range columns are >= nw)
+        idx = jnp.arange(Sp, dtype=jnp.int32)
+        valid = idx[None, :] < nw
+        if has_ws:
+            valid &= idx[None, :] >= ws
+    kernel_outs = iter(outs)
     results = {}
-    for (name, spec), o in zip(plan.sketches, outs):
+    for name, spec in plan.sketches:
+        ops_nm = operands.get(name, {})
+        init = ops_nm.get("init")
         if isinstance(spec, MinHashSpec):
-            results[name] = o[:B]
+            results[name] = next(kernel_outs)[:B]
         elif isinstance(spec, HLLSpec):
-            results[name] = o
+            regs = next(kernel_outs).reshape(-1)[: 1 << spec.b]
+            results[name] = (regs if init is None
+                             else jnp.maximum(regs, init.astype(jnp.int32)))
         elif isinstance(spec, CountMinSpec):
+            init = None if init is None else init.astype(jnp.int32)
             if spec.use_in_kernel:
-                results[name] = o
+                table = next(kernel_outs).reshape(spec.depth, -1)
+                table = table[:, : spec.width]
+                results[name] = table if init is None else table + init
             else:
-                # XLA scatter-add over the kernel-emitted hashes; validity
-                # re-derived from the padded n_windows exactly as in-kernel
-                # (padded rows have nw=0, out-of-range columns are >= nw),
-                # and the carry-in table (if any) seeds the scatter
-                ops_nm = operands.get(name, {}) if operands else {}
-                idx = jnp.arange(Sp, dtype=jnp.int32)
-                valid = idx[None, :] < nw
-                if has_ws:
-                    valid &= idx[None, :] >= ws
-                init = ops_nm.get("init")
                 results[name] = _kref.cms_reduce(
-                    o, valid, ops_nm["a"].astype(_U32),
-                    ops_nm["b"].astype(_U32), spec.log2_width,
-                    init=None if init is None else init.astype(jnp.int32))
+                    h_out, valid, ops_nm["a"].astype(_U32),
+                    ops_nm["b"].astype(_U32), spec.log2_width, init=init)
         else:
-            results[name] = o[:B, 0]
+            hits = _kref._bloom_reduce(h_out, hb_out, valid,
+                                       ops_nm["bits"].astype(_U32), spec.k,
+                                       spec.log2_m)[:B]
+            results[name] = (hits if init is None
+                             else hits + init.astype(jnp.int32))
     return results
 
 

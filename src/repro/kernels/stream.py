@@ -102,7 +102,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.contracts import kernel_contract
@@ -192,7 +192,9 @@ def init_state(plan: SketchPlan, batch: int, *, carry: Optional[Dict] = None,
     for name, spec in plan.sketches:
         shape, dtype, fill = spec.state_struct(Bp)
         if name in carry:
-            got = jnp.asarray(carry[name], dtype)
+            # a copy: the state is donated to the executors, and the
+            # caller's array must outlive it
+            got = jnp.array(carry[name], dtype, copy=True)
             want = spec.state_struct(batch)[0]
             if got.shape != want:
                 raise ValueError(
@@ -342,7 +344,7 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
         local, mesh=mesh,
         in_specs=(st_spec, chunk_axis,
                   chunk_axis if xs_b is not None else None, chunk_axis),
-        out_specs=st_spec, check_rep=False)(state, xs_x, xs_b, xs_len)
+        out_specs=st_spec, check_vma=False)(state, xs_x, xs_b, xs_len)
     out = dict(state["sketch"])
     for name, (init, merge) in carry.items():
         out[name] = merge(out[name], init)

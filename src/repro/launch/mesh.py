@@ -6,17 +6,25 @@ jax device state — the dry-run driver must set XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model shards by GSPMD propagation from its parameter
+    # shardings and logical-name constraints (nn/sharding.py), not by
+    # explicit per-array sharding types (jax.make_mesh's default)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; 2 pods = 512 chips for the multi-pod dry-run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small host-device mesh for tests (requires matching device count)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))

@@ -56,7 +56,7 @@ def test_census_recurses_into_nested_regions():
     jx = jax.make_jaxpr(fn)(x)
     census = primitive_census(jx)
     assert census.get("pallas_call") == 1
-    # the fused kernel's body is reached through the pjit/pallas nesting
+    # the fused kernel's body is reached through the jit/pallas nesting
     assert count_primitive(jx, "pallas_call") == 1
     assert not any(collective_census(jx).values())
 
@@ -64,7 +64,7 @@ def test_census_recurses_into_nested_regions():
 def test_x64_leak_detection():
     jx_clean = jax.make_jaxpr(lambda x: x + jnp.uint32(1))(jnp.uint32(0))
     assert x64_leaks(jx_clean) == []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx_wide = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jnp.float32(0))
     assert x64_leaks(jx_wide)
@@ -145,13 +145,13 @@ def test_unexpected_collective_is_flagged():
     """A collective in a contract declared collectives='none' must fire."""
     contract = contracts.contract_for(api.run)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def with_psum(x):
         return shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P(),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
 
     jx = jax.make_jaxpr(with_psum)(jnp.ones((4,), jnp.float32))
     findings = contracts.check_contract(contract, jx,

@@ -221,22 +221,39 @@ def test_hedge_targets_a_different_replica_and_wins_are_attributed():
 
 def test_watchdog_slow_signal_triggers_proactive_hedge():
     """Once the per-worker latency Watchdog flags a straggler, hedges fire
-    immediately (before hedge_after_s), and verdicts still match."""
+    immediately (before hedge_after_s), and verdicts still match.
+
+    The straggler signal is driven by call counts, not by wall time:
+    worker 1's Watchdog still records every completed call's latency in
+    its real windowed history, but its verdict is a breach on every call
+    after its warm-up. hedge_after_s is far beyond any call, so every
+    hedge in the run is a proactive one. (The median + MAD verdict itself
+    is tested on counts in test_train.)"""
     docs = _docs(n=32, seed=8)
     with MinHashDeduper(_cfg()) as ref, \
          DedupService(_cfg(), ServiceConfig(n_workers=4, replication=2,
-                                            hedge_after_s=0.05,
-                                            watchdog_warmup=4)) as svc:
+                                            hedge_after_s=30.0,
+                                            watchdog_warmup=4,
+                                            watchdog_window=8)) as svc:
+        wd = svc._wd[1]
+        real_observe = wd.observe
+
+        def observe(dt, step):
+            real_observe(dt, step)
+            return len(wd.times) > wd.warmup
+
+        wd.observe = observe
         want0 = ref.add_batch(docs[:16])
-        got0 = svc.add_batch(docs[:16])       # warm the latency envelope
-        svc.workers[1].delay_s = 0.08
+        got0 = svc.add_batch(docs[:16])       # worker 1 passes its warm-up
         want1 = ref.add_batch(docs[16:])
         got1 = np.concatenate([svc.add_batch(docs[16:24]),
                                svc.add_batch(docs[24:])])
         tele = svc.telemetry()
     np.testing.assert_array_equal(got0, want0)
     np.testing.assert_array_equal(got1, want1)
+    assert len(wd.times) == wd.window       # the history was trimmed
     assert tele["proactive_hedges"] >= 1
+    assert tele["hedges"] == tele["proactive_hedges"]
     assert tele["lost_bands"] == 0
 
 

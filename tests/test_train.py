@@ -242,6 +242,27 @@ def test_watchdog_flags_stragglers():
     assert len(w.stragglers) <= 2
 
 
+def test_watchdog_window_flags_by_count_and_forgets():
+    """The windowed feed a DedupService gives each worker: median + MAD
+    over the last ``window`` durations only, both histories trimmed."""
+    w = Watchdog(factor=3.0, warmup=4, window=8)
+    flagged = []
+    for i in range(40):                   # every fourth call is slow
+        slow = i % 4 == 3
+        breach = w.observe(1.0 if slow else 0.01, i)
+        assert breach == (slow and i >= 4), i
+        if breach:
+            flagged.append(i)
+    assert flagged == list(range(7, 40, 4))
+    assert len(w.times) == 8
+    assert w.stragglers == flagged[-8:]
+    # a worker that stays slow stops being an outlier once the window has
+    # forgotten its fast calls
+    verdicts = [w.observe(1.0, 40 + i) for i in range(8)]
+    assert verdicts[0] and not verdicts[-1]
+    assert w.times == [1.0] * 8
+
+
 # ---------------------------------------------------------------------------
 # Gradient compression
 # ---------------------------------------------------------------------------
