@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import Cyclic, General, MinHash, make_family
 from repro.kernels import api, shard, stream
 from repro.kernels import ref as kref
@@ -302,12 +303,12 @@ class MinHashDeduper:
         self._index = BandShardedLSHIndex(cfg.lsh_bands,
                                           workers=cfg.lsh_workers)
         self._sigs: List[np.ndarray] = []
+        self._batches = 0                 # add_batch ordinal, for traces
         self._sig_fn = jax.jit(self._signature_batch_impl)
         self._sig_one_fn = jax.jit(self._signature_unfused_impl)
         # streaming signing: the h1 lookup for one fixed-shape token chunk
         # (one trace; the chunk then flows through stream.update)
-        self._lookup_fn = jax.jit(
-            lambda toks: self.fam._lookup(self.fam_params, toks))
+        self._lookup_fn = jax.jit(self._lookup_impl)
 
     @property
     def _bands(self) -> List[Dict[bytes, List[int]]]:
@@ -354,8 +355,7 @@ class MinHashDeduper:
         self.mh_params = jax.tree_util.tree_map(jnp.asarray, params["mh"])
         self._sig_fn = jax.jit(self._signature_batch_impl)
         self._sig_one_fn = jax.jit(self._signature_unfused_impl)
-        self._lookup_fn = jax.jit(
-            lambda toks: self.fam._lookup(self.fam_params, toks))
+        self._lookup_fn = jax.jit(self._lookup_impl)
 
     def import_state(self, tree: Dict) -> None:
         """Restore from :meth:`export_state`'s tree: params first, then the
@@ -376,6 +376,12 @@ class MinHashDeduper:
                                                  workers=self.cfg.lsh_workers)
 
     # -- signing ------------------------------------------------------------
+
+    def _lookup_impl(self, tokens: jnp.ndarray) -> jnp.ndarray:
+        """h1 of every token of a chunk block (the streaming signer's
+        lookup, jitted as ``_lookup_fn``)."""
+        with jax.named_scope("dedup.sign.lookup"):
+            return self.fam._lookup(self.fam_params, tokens)
 
     def _signature_batch_impl(self, tokens: jnp.ndarray,
                               n_windows: jnp.ndarray) -> jnp.ndarray:
@@ -431,8 +437,13 @@ class MinHashDeduper:
         signs to the sentinel signature, exactly as the one-shot path masks
         it. Non-fused families fall back to the bucketed oracle.
         """
-        if self.plan is None:
-            return self._signature_many_bucketed(docs)
+        with obs.span("dedup.sign"):
+            if self.plan is None:
+                return self._signature_many_bucketed(docs)
+            return self._signature_many_streamed(docs)
+
+    def _signature_many_streamed(self, docs: Sequence[np.ndarray]
+                                 ) -> np.ndarray:
         cfg = self.cfg
         D = len(docs)
         out = np.empty((D, cfg.n_signatures), np.uint32)
@@ -467,15 +478,16 @@ class MinHashDeduper:
                 while done < n_chunks:
                     rem = n_chunks - done
                     T = T0 if rem >= T0 else 1 << int(np.ceil(np.log2(rem)))
-                    toks = np.zeros((T, Bt, Cs), np.uint32)
-                    lengths = np.zeros((T, Bt), np.int32)
-                    for t in range(T):
-                        lo = (done + t) * Cs
-                        for r, d in enumerate(group):
-                            v = int(np.clip(len(d) - lo, 0, Cs))
-                            if v:
-                                toks[t, r, :v] = d[lo : lo + v]
-                                lengths[t, r] = v
+                    with obs.span("dedup.sign.pack"):
+                        toks = np.zeros((T, Bt, Cs), np.uint32)
+                        lengths = np.zeros((T, Bt), np.int32)
+                        for t in range(T):
+                            lo = (done + t) * Cs
+                            for r, d in enumerate(group):
+                                v = int(np.clip(len(d) - lo, 0, Cs))
+                                if v:
+                                    toks[t, r, :v] = d[lo : lo + v]
+                                    lengths[t, r] = v
                     done += T
                     # h1 lookup dispatches async; the block rides to the
                     # device already hash-mapped
@@ -487,8 +499,10 @@ class MinHashDeduper:
                                 operands=operands, impl=cfg.impl,
                                 donate=cfg.stream_donate, mesh=self.mesh,
                                 data_shards=cfg.data_shards)
-            sigs = np.asarray(stream.finalize(self.plan, state,
-                                              batch=Bt)["sig"])
+            # the group's one blocking device-to-host fetch
+            with obs.span("dedup.sign.fetch"):
+                sigs = np.asarray(stream.finalize(self.plan, state,
+                                                  batch=Bt)["sig"])
             out[sel] = sigs[: len(group)]
         return out
 
@@ -578,19 +592,29 @@ class MinHashDeduper:
         flags = np.zeros(D, bool)
         if D == 0:
             return flags
-        sigs = self.signature_many(docs)
-        kb = self._band_keys(sigs)                       # (D, bands) void
-        index_cand, batch_cand = self._index.probe_batch(kb)
-        gid: List[Optional[int]] = [None] * D
-        for i in range(D):
-            cands = set(index_cand[i])
-            cands.update(gid[j] for j in batch_cand[i] if gid[j] is not None)
-            best_j, best_id = self._best_match(sigs[i], sorted(cands))
-            if best_id is not None and best_j >= self.cfg.threshold:
-                flags[i] = True
-            else:
-                gid[i] = self._insert(sigs[i],
-                                      [k.tobytes() for k in kb[i]])
+        self._batches += 1
+        with obs.span("dedup.add_batch", batch=self._batches):
+            sigs = self.signature_many(docs)
+            kb = self._band_keys(sigs)                   # (D, bands) void
+            with obs.span("dedup.probe"):
+                index_cand, batch_cand = self._index.probe_batch(kb)
+            gid: List[Optional[int]] = [None] * D
+            with obs.span("dedup.verify"):
+                for i in range(D):
+                    cands = set(index_cand[i])
+                    cands.update(gid[j] for j in batch_cand[i]
+                                 if gid[j] is not None)
+                    best_j, best_id = self._best_match(sigs[i], sorted(cands))
+                    if best_id is not None and best_j >= self.cfg.threshold:
+                        flags[i] = True
+                    else:
+                        gid[i] = len(self._sigs)
+                        self._sigs.append(sigs[i])
+            # the kept documents enter the index after the batch is
+            # verified: the loop reads candidates from the probe alone
+            with obs.span("dedup.insert"):
+                for i in np.flatnonzero(~flags):
+                    self._index.insert(gid[i], [k.tobytes() for k in kb[i]])
         return flags
 
     def check_and_add(self, tokens: np.ndarray) -> Tuple[bool, Optional[int], float]:

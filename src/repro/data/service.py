@@ -84,6 +84,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.data import durable
 from repro.data.dedup import (DedupConfig, MinHashDeduper, pack_band,
                               unpack_band)
@@ -272,6 +273,7 @@ class DedupService:
         self.r = min(self.svc.replication, self.svc.n_workers)
         self._stride = max(1, self.svc.n_workers // self.r)
         self._sigs: List[np.ndarray] = []
+        self._batches = 0                 # add_batch ordinal, for traces
         # (band, replica) liveness + failure-streak bookkeeping
         self.dead = np.zeros((self.n_bands, self.r), bool)
         self._strikes = np.zeros((self.n_bands, self.r), np.int64)
@@ -706,25 +708,31 @@ class DedupService:
         flags = np.zeros(D, bool)
         if D == 0:
             return flags
-        sigs = self.dd.signature_many(docs)
-        kb = self.dd._band_keys(sigs)
-        index_cand, batch_cand = self._probe_batch(kb)
-        inserts: Dict[int, List] = {}
-        gid: List[Optional[int]] = [None] * D
-        for i in range(D):
-            cands = set(index_cand[i])
-            cands.update(gid[j] for j in batch_cand[i] if gid[j] is not None)
-            best_j, best_id = self._best_match(sigs[i], sorted(cands))
-            if best_id is not None and best_j >= self.dd.cfg.threshold:
-                flags[i] = True
-            else:
-                doc_id = len(self._sigs)
-                self._sigs.append(sigs[i])
-                gid[i] = doc_id
-                for b in range(self.n_bands):
-                    inserts.setdefault(b, []).append(
-                        (kb[i, b].tobytes(), doc_id))
-        self._insert_bands(inserts)
+        self._batches += 1
+        with obs.span("dedup.add_batch", batch=self._batches):
+            sigs = self.dd.signature_many(docs)
+            kb = self.dd._band_keys(sigs)
+            with obs.span("dedup.probe"):
+                index_cand, batch_cand = self._probe_batch(kb)
+            inserts: Dict[int, List] = {}
+            gid: List[Optional[int]] = [None] * D
+            with obs.span("dedup.verify"):
+                for i in range(D):
+                    cands = set(index_cand[i])
+                    cands.update(gid[j] for j in batch_cand[i]
+                                 if gid[j] is not None)
+                    best_j, best_id = self._best_match(sigs[i], sorted(cands))
+                    if best_id is not None and best_j >= self.dd.cfg.threshold:
+                        flags[i] = True
+                    else:
+                        doc_id = len(self._sigs)
+                        self._sigs.append(sigs[i])
+                        gid[i] = doc_id
+                        for b in range(self.n_bands):
+                            inserts.setdefault(b, []).append(
+                                (kb[i, b].tobytes(), doc_id))
+            with obs.span("dedup.insert"):
+                self._insert_bands(inserts)
         return flags
 
     def _best_match(self, sig, candidates):

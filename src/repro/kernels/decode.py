@@ -127,13 +127,15 @@ def decode_masks_fused(logits, prefix, ready, bloom, h1, *,
     # double-hashed probes per filter -> one hit word per candidate
     cand = _kref._rotl_const(prefix.astype(_U32), 1, spec.L)[:, None] ^ h1[None, :]
     h = cand & np.uint32(spec.hash_mask)
-    hits = _kref.bloom_probe_hits(h, bloom.astype(_U32), spec.k,
-                                  spec.log2_m).astype(jnp.int32)
+    with jax.named_scope("decode.probe.session"):
+        hits = _kref.bloom_probe_hits(h, bloom.astype(_U32), spec.k,
+                                      spec.log2_m).astype(jnp.int32)
     if spec.has_canary:
         assert canary_bits is not None
-        hits = hits | (_kref.bloom_probe_hits(
-            h, canary_bits.astype(_U32), spec.canary_k,
-            spec.canary_log2_m).astype(jnp.int32) << 1)
+        with jax.named_scope("decode.probe.canary"):
+            hits = hits | (_kref.bloom_probe_hits(
+                h, canary_bits.astype(_U32), spec.canary_k,
+                spec.canary_log2_m).astype(jnp.int32) << 1)
 
     pad = ((0, Bp - B), (0, Vp - V))
     lg = jnp.pad(logits.astype(jnp.float32), pad)
@@ -154,6 +156,7 @@ def decode_masks_fused(logits, prefix, ready, bloom, h1, *,
 
     outs = pl.pallas_call(
         functools.partial(_decode_kernel, V=V, block_v=block_v),
+        name="decode_masks",
         grid=(Bp // block_b, Vp // block_v),
         in_specs=[tile, tile, row, wspec],
         out_specs=(tile,) + (ptile,) * n_masks,

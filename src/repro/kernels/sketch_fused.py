@@ -478,6 +478,7 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     outs = list(pl.pallas_call(
         functools.partial(_plan_kernel, plan=plan, block_s=block_s,
                           has_ws=has_ws, has_init=has_init, emit_h=emit_h),
+        name="sketch_plan",
         grid=grid,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
@@ -657,6 +658,7 @@ def cyclic_rolling_fused(tokens: jnp.ndarray, table: jnp.ndarray, *, n: int,
 
     out = pl.pallas_call(
         functools.partial(_lookup_fused_kernel, n=n, L=L, block_s=block_s),
+        name="lookup_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, block_s), lambda b, j: (b, j),

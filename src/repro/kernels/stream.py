@@ -94,7 +94,6 @@ Entry points:
 """
 from __future__ import annotations
 
-import contextvars
 import functools
 from typing import Dict, Optional
 
@@ -105,6 +104,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.analysis.contracts import kernel_contract
 from repro.kernels import api, shard
 from repro.kernels.plan import CountMinSpec, HLLSpec, SketchPlan
@@ -112,22 +112,16 @@ from repro.kernels.plan import CountMinSpec, HLLSpec, SketchPlan
 _EXECUTORS = ("scan", "grid", "host")
 
 # device dispatches issued by this module's executors (one jitted call = one
-# XLA execution); the one-dispatch-per-stream property is asserted against
-# this counter in tests and reported by the benchmarks. Context-local
-# (contextvars): concurrent streams — asyncio servers, parallel test
-# workers — each observe only their own dispatches instead of racing on a
-# module global
-_dispatches = contextvars.ContextVar("repro.kernels.stream._dispatches",
-                                     default=0)
+# XLA execution), counted as ``stream.dispatches`` by the program's recorder
+# (context-local, so concurrent streams each observe only their own); the
+# one-dispatch-per-stream property is asserted against it in tests and
+# reported by the benchmarks
 
 
 def dispatch_count() -> int:
     """Chunk-executor device dispatches issued in this context."""
-    return _dispatches.get()
+    return obs.counter("stream.dispatches")
 
-
-def _dispatched(n: int = 1) -> None:
-    _dispatches.set(_dispatches.get() + n)
 
 # backends whose runtime implements buffer donation; elsewhere "auto" skips
 # the request (XLA would silently ignore it — harmless, but explicit beats
@@ -179,32 +173,33 @@ def init_state(plan: SketchPlan, batch: int, *, carry: Optional[Dict] = None,
         raise ValueError(f"batch must be >= 1, got {batch}")
     mesh = _resolve_mesh(mesh, data_shards)
     Bp = batch if mesh is None else batch + (-batch % mesh.devices.size)
-    n = plan.hash.n
-    state = {"tail": jnp.zeros((Bp, n - 1), jnp.uint32),
-             "seen": jnp.zeros((Bp,), jnp.int32)}
-    if plan.needs_second_stream:
-        state["tail_b"] = jnp.zeros((Bp, n - 1), jnp.uint32)
-    sketch = {}
     carry = carry or {}
     unknown = set(carry) - set(plan.names)
     if unknown:
         raise ValueError(f"carry for sketches not in plan: {sorted(unknown)}")
-    for name, spec in plan.sketches:
-        shape, dtype, fill = spec.state_struct(Bp)
-        if name in carry:
-            # a copy: the state is donated to the executors, and the
-            # caller's array must outlive it
-            got = jnp.array(carry[name], dtype, copy=True)
-            want = spec.state_struct(batch)[0]
-            if got.shape != want:
-                raise ValueError(
-                    f"carry[{name!r}] shape {got.shape} != state shape {want}")
-            if Bp != batch and spec.state_kind == "row":
-                pad = jnp.full((Bp - batch,) + want[1:], fill, dtype)
-                got = jnp.concatenate([got, pad], axis=0)
-            sketch[name] = got
-        else:
-            sketch[name] = jnp.full(shape, fill, dtype)
+    n = plan.hash.n
+    with jax.named_scope("stream.init"):
+        state = {"tail": jnp.zeros((Bp, n - 1), jnp.uint32),
+                 "seen": jnp.zeros((Bp,), jnp.int32)}
+        if plan.needs_second_stream:
+            state["tail_b"] = jnp.zeros((Bp, n - 1), jnp.uint32)
+        sketch = {}
+        for name, spec in plan.sketches:
+            shape, dtype, fill = spec.state_struct(Bp)
+            if name in carry:
+                # a copy: the state is donated to the executors, and the
+                # caller's array must outlive it
+                got = jnp.array(carry[name], dtype, copy=True)
+                want = spec.state_struct(batch)[0]
+                if got.shape != want:
+                    raise ValueError(f"carry[{name!r}] shape {got.shape} "
+                                     f"!= state shape {want}")
+                if Bp != batch and spec.state_kind == "row":
+                    pad = jnp.full((Bp - batch,) + want[1:], fill, dtype)
+                    got = jnp.concatenate([got, pad], axis=0)
+                sketch[name] = got
+            else:
+                sketch[name] = jnp.full(shape, fill, dtype)
     state["sketch"] = sketch
     return state
 
@@ -311,7 +306,8 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
                             operands), None
 
     if mesh is None:
-        state, _ = jax.lax.scan(step, state, (xs_x, xs_b, xs_len))
+        with jax.named_scope("stream.scan"):
+            state, _ = jax.lax.scan(step, state, (xs_x, xs_b, xs_len))
         return state
 
     # pop the global carries: each shard scans from the sketch identity
@@ -326,7 +322,8 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
     state = dict(state, sketch=sk)
 
     def local(st, xs_x, xs_b, xs_len):
-        st, _ = jax.lax.scan(step, st, (xs_x, xs_b, xs_len))
+        with jax.named_scope("stream.scan"):
+            st, _ = jax.lax.scan(step, st, (xs_x, xs_b, xs_len))
         out = dict(st["sketch"])
         for name, spec in plan.sketches:
             if isinstance(spec, HLLSpec):
@@ -430,7 +427,7 @@ def update(plan: SketchPlan, state: Dict, chunk, *, chunk_b=None,
         lengths = jnp.pad(lengths, (0, Bp - B))
     tile = tuple(sorted(tile_kw.items()))
     fn = _update_donated if _resolve_donate(donate) else _update_plain
-    _dispatched()
+    obs.count("stream.dispatches")
     return fn(plan, ref_path, mesh, tile, state, chunk, chunk_b, lengths,
               operands)
 
@@ -499,7 +496,7 @@ def update_many(plan: SketchPlan, state: Dict, chunks, *, chunk_b=None,
         lengths = jnp.pad(lengths, ((0, 0), (0, Bp - B)))
     tile = tuple(sorted(tile_kw.items()))
     fn = _scan_donated if _resolve_donate(donate) else _scan_plain
-    _dispatched()
+    obs.count("stream.dispatches")
     return fn(plan, ref_path, mesh, tile, None, state, chunks, chunk_b,
               lengths, operands)
 
@@ -749,7 +746,7 @@ def run_stream(plan: SketchPlan, h1v, *, chunk_s: int, h1v_b=None,
             lens = jnp.pad(lens, (0, Bp - B))
         tile = tuple(sorted(tile_kw.items()))
         fn = _scan_donated if _resolve_donate(donate) else _scan_plain
-        _dispatched()
+        obs.count("stream.dispatches")
         state = fn(plan, ref_path, mesh, tile, nc, state, x, xb, lens,
                    operands_n)
     out = finalize(plan, state, batch=B)

@@ -36,7 +36,6 @@ index) before the shard region.
 """
 from __future__ import annotations
 
-import contextvars
 import functools
 from typing import Dict, Optional, Sequence
 
@@ -44,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analysis.contracts import kernel_contract
 from repro.core import gf2
 from repro.kernels import api, shard
@@ -54,22 +54,15 @@ from repro.kernels.stream import _resolve_donate
 _U32 = jnp.uint32
 
 # device dispatches issued by this module (one jitted call = one XLA
-# execution): decode steps, prompt primes and churn ops all count, so the
-# one-dispatch-per-decode-step property is assertable against this counter
-# (same instrumentation contract as kernels.stream.dispatch_count).
-# Context-local (contextvars): pools served from different asyncio tasks or
-# threads each observe their own dispatch count
-_dispatches = contextvars.ContextVar("repro.serve.sessions._dispatches",
-                                     default=0)
+# execution): decode steps, prompt primes and churn ops all count, as
+# ``sessions.dispatches`` in the program's recorder (context-local, the
+# same contract as kernels.stream.dispatch_count), so the
+# one-dispatch-per-decode-step property is assertable against it
 
 
 def dispatch_count() -> int:
     """Session-pool device dispatches issued in this context."""
-    return _dispatches.get()
-
-
-def _dispatched(n: int = 1) -> None:
-    _dispatches.set(_dispatches.get() + n)
+    return obs.counter("sessions.dispatches")
 
 
 def init_state(spec: DecodeSpec, capacity: int) -> Dict[str, jnp.ndarray]:
@@ -179,28 +172,32 @@ def _step_core(spec: DecodeSpec, ref_path: bool, tile, temperature: float,
                      h1, canary_bits=canary_bits,
                      impl="ref" if ref_path else "pallas", **dict(tile))
     masked = out["logits"]
-    if top_k:
-        kth = jax.lax.top_k(masked, top_k)[0][:, -1:]
-        masked = jnp.where(masked < kth, _kref.NEG_LOGIT, masked)
-    if temperature == 0.0:
-        token = jnp.argmax(masked, axis=-1).astype(jnp.int32)
-    else:
-        # per-row categorical with per-row keys: the sample a session draws
-        # depends only on its own slot, never on batch layout or mesh size
-        token = jax.vmap(
-            lambda k, l: jax.random.categorical(k, l / temperature)
-        )(keys, masked).astype(jnp.int32)
-    new_state = _advance_rows(spec, state, h1[token], live)
-    inc = jnp.where(live, _popcount_rows(out["banned"]), np.uint32(0))
-    (new_state["banned_lo"],
-     new_state["banned_hi"]) = _accum_u64(state["banned_lo"],
-                                          state["banned_hi"], inc)
-    if spec.has_canary:
-        cinc = jnp.where(live, _popcount_rows(out["canary"]), np.uint32(0))
-        (new_state["canary_lo"],
-         new_state["canary_hi"]) = _accum_u64(state["canary_lo"],
-                                              state["canary_hi"], cinc)
-    new_state["steps"] = state["steps"] + live.astype(_U32)
+    with jax.named_scope("decode.sample"):
+        if top_k:
+            kth = jax.lax.top_k(masked, top_k)[0][:, -1:]
+            masked = jnp.where(masked < kth, _kref.NEG_LOGIT, masked)
+        if temperature == 0.0:
+            token = jnp.argmax(masked, axis=-1).astype(jnp.int32)
+        else:
+            # per-row categorical with per-row keys: the sample a session
+            # draws depends only on its own slot, never on batch layout or
+            # mesh size
+            token = jax.vmap(
+                lambda k, l: jax.random.categorical(k, l / temperature)
+            )(keys, masked).astype(jnp.int32)
+    with jax.named_scope("decode.advance"):
+        new_state = _advance_rows(spec, state, h1[token], live)
+        inc = jnp.where(live, _popcount_rows(out["banned"]), np.uint32(0))
+        (new_state["banned_lo"],
+         new_state["banned_hi"]) = _accum_u64(state["banned_lo"],
+                                              state["banned_hi"], inc)
+        if spec.has_canary:
+            cinc = jnp.where(live, _popcount_rows(out["canary"]),
+                             np.uint32(0))
+            (new_state["canary_lo"],
+             new_state["canary_hi"]) = _accum_u64(state["canary_lo"],
+                                                  state["canary_hi"], cinc)
+        new_state["steps"] = state["steps"] + live.astype(_U32)
     return token, new_state
 
 
@@ -343,7 +340,7 @@ class SessionPool:
                              f"slot(s) of {self.capacity}")
         slots = np.array([self._free.pop() for _ in range(count)],
                          dtype=np.int64)
-        _dispatched()
+        obs.count("sessions.dispatches")
         self.state = _churn("reset", self.state, self._mask(slots))
         return slots
 
@@ -351,7 +348,7 @@ class SessionPool:
         """Deactivate sessions and return their slots to the free list.
         State (telemetry included) survives until the slot is re-admitted."""
         slots = np.atleast_1d(np.asarray(slots, dtype=np.int64))
-        _dispatched()
+        obs.count("sessions.dispatches")
         self.state = _churn("evict", self.state, self._mask(slots))
         self._free.extend(int(s) for s in slots)
 
@@ -359,7 +356,7 @@ class SessionPool:
         """Zero the state of live sessions in place (fresh conversation,
         same slot)."""
         slots = np.atleast_1d(np.asarray(slots, dtype=np.int64))
-        _dispatched()
+        obs.count("sessions.dispatches")
         self.state = _churn("reset", self.state, self._mask(slots))
 
     # -- the decode plane -------------------------------------------------
@@ -380,7 +377,7 @@ class SessionPool:
                 raise ValueError(f"lengths shape {lengths.shape} != "
                                  f"({self.capacity},)")
         fn = _prime_donated if self._donate else _prime_plain
-        _dispatched()
+        obs.count("sessions.dispatches")
         self.state = fn(self.spec, self.mesh, T, self.state, tokens,
                         lengths, self.h1)
 
@@ -404,7 +401,7 @@ class SessionPool:
         if key is None:
             key = jax.random.PRNGKey(0)
         fn = _step_donated if self._donate else _step_plain
-        _dispatched()
+        obs.count("sessions.dispatches")
         token, self.state = fn(self.spec, self._ref_path, self.mesh,
                                self._tile, float(temperature), int(top_k),
                                self.state, logits, self.h1,
