@@ -59,6 +59,7 @@ class KernelContract:
     not checked for that entry)."""
 
     pallas_calls: Optional[int] = None   # exact count on the fused path
+    # loops are counted outside pallas_call bodies (check_contract)
     scans: Optional[int] = None          # exact lax.scan count
     while_loops: Optional[int] = None    # exact while count
     collectives: Union[str, Mapping[str, int]] = "none"
@@ -147,12 +148,15 @@ def check_contract(contract: KernelContract, jaxpr, *,
     and directly by test suites on seeded-violation fixtures."""
     findings: List[str] = []
     jaxpr = jxa.as_jaxpr(jaxpr)
-    for field, prim in (("pallas_calls", "pallas_call"), ("scans", "scan"),
-                        ("while_loops", "while")):
+    # loops are counted outside kernel bodies: a loop inside a kernel runs
+    # within its one launch, an XLA-level loop is a loop of device work
+    for field, prim, kernels in (("pallas_calls", "pallas_call", True),
+                                 ("scans", "scan", False),
+                                 ("while_loops", "while", False)):
         want = getattr(contract, field)
         if want is None:
             continue
-        got = jxa.count_primitive(jaxpr, prim)
+        got = jxa.count_primitive(jaxpr, prim, kernels)
         if got != want:
             findings.append(f"{prim}: counted {got}, contract says {want}")
     allow = expected_collectives or {}

@@ -18,8 +18,8 @@ layers (``data/``, ``serve/``, ``kernels/decode.py``) with two rules:
   own shifts (probe word index ``>> 5``, HLL rank split) use constants or
   unrelated widths and never match.
 * ``DS2`` — the known probe-derivation entry points
-  (``ref.bloom_probe_hits``, ``sessions._bloom_add_rows``) must receive a
-  *masked* hash argument: the
+  (``ref.bloom_probe_hits``, ``decode._vmem_probe_hits``,
+  ``sessions._bloom_add_rows``) must receive a *masked* hash argument: the
   argument expression (or the local name it was assigned from, tracked to a
   fixpoint inside the enclosing function) must route through ``hash_mask``.
 
@@ -55,6 +55,7 @@ SCOPE = ("src/repro/data", "src/repro/serve", "src/repro/kernels/decode.py")
 # masked hash
 PROBE_CALLEES: Dict[str, int] = {
     "bloom_probe_hits": 0,      # ref.py — the probe oracle
+    "_vmem_probe_hits": 0,      # kernels/decode.py — in-kernel probes
     "_bloom_add_rows": 1,       # serve/sessions.py — filter insert
 }
 

@@ -87,18 +87,24 @@ def _sub_jaxprs(eqn):
                 yield u
 
 
-def iter_eqns(jaxpr) -> Iterator:
-    """Depth-first over every equation, recursing into nested jaxprs."""
+def iter_eqns(jaxpr, kernels: bool = True) -> Iterator:
+    """Depth-first over every equation, recursing into nested jaxprs;
+    with ``kernels=False`` not into the bodies of ``pallas_call``s (what
+    runs inside one kernel launch)."""
     jaxpr = as_jaxpr(jaxpr)
     for eqn in jaxpr.eqns:
         yield eqn
+        if not kernels and eqn.primitive.name == "pallas_call":
+            continue
         for sub in _sub_jaxprs(eqn):
-            yield from iter_eqns(sub)
+            yield from iter_eqns(sub, kernels)
 
 
-def count_primitive(jaxpr, name: str) -> int:
-    """Occurrences of primitive ``name``, recursing into nested jaxprs."""
-    return sum(1 for eqn in iter_eqns(jaxpr) if eqn.primitive.name == name)
+def count_primitive(jaxpr, name: str, kernels: bool = True) -> int:
+    """Occurrences of primitive ``name``, recursing into nested jaxprs
+    (into kernel bodies unless ``kernels=False``)."""
+    return sum(1 for eqn in iter_eqns(jaxpr, kernels)
+               if eqn.primitive.name == name)
 
 
 def primitive_census(jaxpr) -> Dict[str, int]:

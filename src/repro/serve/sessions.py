@@ -48,6 +48,7 @@ from repro.analysis.contracts import kernel_contract
 from repro.core import gf2
 from repro.kernels import api, shard
 from repro.kernels import ref as _kref
+from repro.kernels.decode import all_probes_in_vmem
 from repro.kernels.plan import DecodeSpec
 from repro.kernels.stream import _resolve_donate
 
@@ -57,7 +58,9 @@ _U32 = jnp.uint32
 # execution): decode steps, prompt primes and churn ops all count, as
 # ``sessions.dispatches`` in the program's recorder (context-local, the
 # same contract as kernels.stream.dispatch_count), so the
-# one-dispatch-per-decode-step property is assertable against it
+# one-dispatch-per-decode-step property is assertable against it; decode
+# steps whose kernel probes every filter from VMEM also count as
+# ``sessions.vmem_probe_steps``
 
 
 def dispatch_count() -> int:
@@ -304,6 +307,8 @@ class SessionPool:
                     f"without padding")
         self.mesh = mesh
         self._ref_path = api.use_ref(impl)
+        self._vmem_probes = (not self._ref_path
+                             and all_probes_in_vmem(spec))
         self._donate = _resolve_donate(donate)
         self._tile = tuple(sorted(tile_kw.items()))
         h1 = jnp.asarray(h1, _U32)
@@ -402,6 +407,8 @@ class SessionPool:
             key = jax.random.PRNGKey(0)
         fn = _step_donated if self._donate else _step_plain
         obs.count("sessions.dispatches")
+        if self._vmem_probes:
+            obs.count("sessions.vmem_probe_steps")
         token, self.state = fn(self.spec, self._ref_path, self.mesh,
                                self._tile, float(temperature), int(top_k),
                                self.state, logits, self.h1,

@@ -12,6 +12,7 @@ every checker here is exercised from both sides:
   the contract matrix must all come back empty, which is exactly what
   ``python -m repro.analysis`` (CI: ``./test.sh --analyze``) enforces.
 """
+import dataclasses
 import textwrap
 from pathlib import Path
 
@@ -159,6 +160,49 @@ def test_unexpected_collective_is_flagged():
     assert any("psum" in f for f in findings), findings
 
 
+def _session_step_jaxpr(spec, steps=None):
+    """The session pool's decode step on the kernel path; with ``steps``,
+    that many steps as one XLA-level ``lax.scan``."""
+    from repro.serve import sessions as sess
+    B, V = 8, 256
+    rng = np.random.default_rng(0)
+    state = sess.init_state(spec, B)
+    logits = jnp.asarray(rng.standard_normal((B, V)), jnp.float32)
+    h1 = jnp.asarray(rng.integers(0, 2**32, V, dtype=np.uint32))
+    cb = jnp.zeros((spec.canary_words,), jnp.uint32)
+
+    def step(st, t):
+        return sess._step_body(spec, False, None, (), 0.0, 0, st, logits,
+                               h1, cb, jax.random.PRNGKey(0), t)
+
+    if steps is None:
+        return jax.make_jaxpr(step)(state, jnp.int32(0))
+    return jax.make_jaxpr(lambda st: jax.lax.scan(
+        lambda c, t: step(c, t)[::-1], st,
+        jnp.arange(steps, dtype=jnp.int32)))(state)
+
+
+def test_xla_level_scan_in_session_step_is_flagged():
+    """``SessionPool.step`` declares no loop: the kernel's own loops over
+    candidate slices and filter chunks run inside its one launch and are
+    not counted, a ``lax.scan`` of steps around it is."""
+    from repro.kernels.plan import DecodeSpec
+    from repro.serve import sessions as sess
+    spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20)
+    # the donation half needs lowerings; the loop counts need only jaxprs
+    contract = dataclasses.replace(
+        contracts.contract_for(sess.SessionPool.step), donated=())
+    jx = _session_step_jaxpr(spec)
+    assert count_primitive(jx, "scan") > 0            # inside the kernel
+    assert count_primitive(jx, "scan", kernels=False) == 0
+    assert contracts.check_contract(contract, jx,
+                                    expected_collectives={}) == []
+    findings = contracts.check_contract(contract,
+                                        _session_step_jaxpr(spec, steps=2),
+                                        expected_collectives={})
+    assert any(f.startswith("scan: counted 1") for f in findings), findings
+
+
 # ---------------------------------------------------------------------------
 # seeded discard violations (Theorems 1-2)
 # ---------------------------------------------------------------------------
@@ -185,6 +229,36 @@ def test_probe_from_undiscarded_bits_is_flagged():
 
     assert discard.trace_findings(jax.make_jaxpr(good)(jnp.uint32(7)),
                                   mask) == []
+
+
+@pytest.mark.parametrize("masked", [True, False])
+def test_kernel_probe_from_undiscarded_bits_is_flagged(masked):
+    """The trace pass enters ``pallas_call`` bodies: a decode kernel that
+    masks the candidate hash but probes its VMEM filter with the raw value
+    is flagged; the same kernel probing the masked hash is clean."""
+    from jax.experimental import pallas as pl
+    from repro.kernels import decode
+    from repro.kernels import ref as kref
+    from repro.kernels.plan import DecodeSpec
+    spec = DecodeSpec(n=4, log2_m=8)
+
+    def kernel(prefix_ref, h1_ref, bloom_ref, out_ref):
+        cand = kref._rotl_const(prefix_ref[...], 1, spec.L) ^ h1_ref[...]
+        h = cand & np.uint32(spec.hash_mask)
+        out_ref[...] = decode._vmem_probe_hits(
+            h if masked else cand, spec.k, spec.log2_m,
+            [bloom_ref[...]]).astype(jnp.int32)
+
+    call = pl.pallas_call(kernel, interpret=True,
+                          out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32))
+    jx = jax.make_jaxpr(call)(jnp.zeros((8, 1), jnp.uint32),
+                              jnp.zeros((1, 128), jnp.uint32),
+                              jnp.zeros((8, 128), jnp.uint32))
+    findings = discard.trace_findings(jx, spec.hash_mask)
+    if masked:
+        assert findings == []
+    else:
+        assert findings and "mul" in findings[0], findings
 
 
 def test_static_discard_rules_on_fixture(tmp_path):
@@ -357,6 +431,15 @@ def test_clean_tree_zero_lint_findings():
 def test_clean_tree_zero_discard_findings():
     assert discard.static_findings() == []
     assert discard.verify_decode_discard() == []
+
+
+def test_decode_cell_spec_zero_discard_findings():
+    """The decode cell's spec: both filters probed inside the kernel."""
+    from repro.kernels.decode import all_probes_in_vmem
+    from repro.kernels.plan import DecodeSpec
+    spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20)
+    assert all_probes_in_vmem(spec)
+    assert discard.verify_decode_discard(spec) == []
 
 
 def test_registry_covers_every_entry_point():

@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from repro.core import gf2
-from repro.kernels import api, ref, shard
+from repro import obs
+from repro.kernels import api, decode, ref, shard
 from repro.kernels.plan import DecodeSpec
 from repro.serve import sessions as sess
 from repro.serve import telemetry
@@ -64,6 +65,41 @@ def test_fused_bitparity_vs_oracle(n, V, canary):
     for key in a:
         np.testing.assert_array_equal(np.asarray(a[key]), np.asarray(b[key]),
                                       err_msg=key)
+
+
+# the kernel probes filters of up to decode.VMEM_PROBE_MAX_WORDS words from
+# VMEM (one lane gather per 128-word chunk); larger ones through XLA
+# gathers ahead of the kernel: (n, log2_m, canary_log2_m, V, block_b)
+VMEM_CASES = {
+    "m5-one-word-bb8": (4, 5, 0, 300, 8),
+    "m12-canary10-bb16": (4, 12, 10, 4096 + 77, 16),
+    "m14-canary20-bb8": (4, 14, 20, 4096 + 300, 8),
+    "m14-canary20-bb16": (4, 14, 20, 2 * 4096 + 5, 16),
+    "degraded-n33-m14-canary10": (33, 14, 10, 1000, 8),
+    "session-past-budget": (4, 21, 10, 700, 8),
+    "canary-past-budget": (4, 14, 21, 700, 16),
+    "both-past-budget": (4, 21, 21, 700, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(VMEM_CASES))
+def test_fused_vmem_probes_bitparity_vs_oracle(case):
+    n, log2_m, canary, V, block_b = VMEM_CASES[case]
+    spec = DecodeSpec(n=n, L=32, log2_m=log2_m, k=2, canary_log2_m=canary)
+    assert decode.all_probes_in_vmem(spec) == ("past-budget" not in case)
+    rng = np.random.default_rng(log2_m * 100 + canary + V)
+    logits, prefix, ready, bloom, h1, cb = _rand_inputs(rng, spec, 19, V)
+    a = api.decode(spec, logits, prefix, ready, bloom, h1, canary_bits=cb,
+                   impl="ref")
+    b = api.decode(spec, logits, prefix, ready, bloom, h1, canary_bits=cb,
+                   impl="pallas", block_b=block_b)
+    assert set(a) == set(b)
+    for key in a:
+        np.testing.assert_array_equal(np.asarray(a[key]), np.asarray(b[key]),
+                                      err_msg=key)
+    assert np.asarray(a["banned"]).any()
+    if canary:
+        assert np.asarray(a["canary"]).any()
 
 
 @pytest.mark.parametrize("L", [16, 32])
@@ -240,6 +276,37 @@ def test_pool_step_one_dispatch_and_oracle_parity():
                          st["bloom"], h1, impl="ref")
     np.testing.assert_array_equal(
         np.asarray(tok), np.asarray(jnp.argmax(ref_out["logits"], axis=-1)))
+
+
+@pytest.mark.parametrize("log2_m,vmem_steps", [(14, 3), (21, 0)])
+def test_pool_counts_vmem_probe_steps(log2_m, vmem_steps):
+    """``sessions.vmem_probe_steps`` counts the decode steps whose kernel
+    probes both filters from VMEM: every step at the decode cell's spec,
+    none once the no-repeat filter is past the VMEM bound."""
+    spec = DecodeSpec(n=4, log2_m=log2_m, k=2, canary_log2_m=20)
+    V, C = 300, 8
+    rng = np.random.default_rng(log2_m)
+    h1 = rng.integers(0, 2**32, size=V, dtype=np.uint32)
+    canary = rng.integers(0, 2**32, size=spec.canary_words, dtype=np.uint32)
+    pool = sess.SessionPool(spec, C, h1, canary_bits=canary, impl="pallas")
+    ref_pool = sess.SessionPool(spec, C, h1, canary_bits=canary, impl="ref")
+    prompts = rng.integers(0, V, size=(C, 6), dtype=np.int32)
+    for p in (pool, ref_pool):
+        p.admit(C)
+        p.prime(prompts)
+    d0 = sess.dispatch_count()
+    v0 = obs.counter("sessions.vmem_probe_steps")
+    for _ in range(3):
+        logits = rng.standard_normal((C, V)).astype(np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(pool.step(logits, temperature=0.0)),
+            np.asarray(ref_pool.step(logits, temperature=0.0)))
+    assert obs.counter("sessions.vmem_probe_steps") - v0 == vmem_steps
+    assert sess.dispatch_count() - d0 == 6
+    for k in pool.state:
+        np.testing.assert_array_equal(np.asarray(pool.state[k]),
+                                      np.asarray(ref_pool.state[k]),
+                                      err_msg=k)
 
 
 def test_pool_greedy_never_repeats_ngram():

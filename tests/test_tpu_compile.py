@@ -2,7 +2,8 @@
 
 JAX describes a ``v5e:2x2`` topology and the installed TPU compiler
 compiles for one of its devices: what Mosaic refuses (unaligned blocks,
-unsigned reductions, gathers from VMEM) fails here, at no chip time. Only
+unsigned reductions, gathers wider than one vreg) fails here, at no chip
+time. Only
 shapes are passed, nothing runs; the interpret-mode parity suites decide
 correctness. Each test asserts that the compiled program holds the kernel
 (``tpu_custom_call``), so a jnp fallback cannot pass for it.
@@ -125,14 +126,34 @@ def test_grid_stream_compiles(one_chip, compiled_as_tpu):
         plan, False, None, (("block_s", 512),), state, x, x, lens, ops))
 
 
-def test_decode_kernel_compiles(one_chip):
-    # the Llama 3 vocabulary with the shared decontam canary
+def _decode_compiled(one_chip, sessions, V):
+    """``decode_masks_fused`` compiled at (sessions, V) with the decode
+    cell's plane: both Bloom filters probed inside the kernel."""
     spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20)
-    V = 128256
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn = functools.partial(decode_masks_fused, spec=spec, interpret=False)
-    _assert_kernel(jax.jit(
+    return jax.jit(
         lambda lg, p, r, bl, h, c: fn(lg, p, r, bl, h, canary_bits=c)).lower(
-            s((B, V), jnp.float32), s((B,), U32), s((B,), jnp.int32),
-            s((B, spec.n_words), U32), s((V,), U32),
-            s((spec.canary_words,), U32)))
+            s((sessions, V), jnp.float32), s((sessions,), U32),
+            s((sessions,), jnp.int32), s((sessions, spec.n_words), U32),
+            s((V,), U32), s((spec.canary_words,), U32)).compile().as_text()
+
+
+def _assert_no_candidate_gather(text):
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert not gathers, gathers[:3]
+
+
+def test_decode_kernel_compiles(one_chip):
+    # the Llama 3 vocabulary with the shared decontam canary
+    text = _decode_compiled(one_chip, B, 128256)
+    assert "tpu_custom_call" in text
+    _assert_no_candidate_gather(text)
+
+
+def test_decode_kernel_compiles_at_cell_shape(one_chip):
+    # decode.kimi-k2-256: 256 sessions over Kimi-K2's 163840 candidates;
+    # the probes are lane gathers inside the kernel, no XLA gather remains
+    text = _decode_compiled(one_chip, 256, 163840)
+    assert "tpu_custom_call" in text
+    _assert_no_candidate_gather(text)
