@@ -9,7 +9,9 @@ prompts and takes one warm step.
 
 The window steps the pool on the ring, one logits array after another,
 until ``seconds`` have passed; each step ends when its tokens are on the
-host, as a server needs them. The last step runs to its end.
+host, as a server needs them. The last step runs to its end. The wall
+time of every window step is kept; its quantiles are facts for the log,
+not metrics.
 """
 from __future__ import annotations
 
@@ -19,6 +21,16 @@ import numpy as np
 
 from bench import generate
 from bench.spans import Spans
+
+
+def step_quantiles(ms: np.ndarray) -> dict:
+    """Median, 99th percentile and longest of the window's step times
+    (ms), the steps over twice the median, and the mean of the first 8."""
+    p50 = float(np.median(ms))
+    return {"step_ms_p50": p50, "step_ms_p99": float(np.quantile(ms, 0.99)),
+            "step_ms_max": float(ms.max()),
+            "steps_over_2x_median": int((ms > 2 * p50).sum()),
+            "step_ms_first8_mean": float(ms[:8].mean())}
 
 
 class Driver:
@@ -75,15 +87,16 @@ class Driver:
 
     def window(self, seconds: float, spans: Spans) -> dict:
         t = len(self.tokens)
-        t0 = time.perf_counter()
-        deadline = t0 + seconds
+        ends = [time.perf_counter()]
+        deadline = ends[0] + seconds
         while True:
             with spans.span("bench.step"):
                 self.tokens.append(self._step(t))
             t += 1
-            if time.perf_counter() >= deadline:
+            ends.append(time.perf_counter())
+            if ends[-1] >= deadline:
                 break
-        t1 = time.perf_counter()
+        t0, t1 = ends[0], ends[-1]
         steps = t - 1
         carry = sum(int(np.prod(v.shape)) * v.dtype.itemsize
                     for v in self.pool.state.values())
@@ -91,7 +104,8 @@ class Driver:
                 "units": steps * self.sessions, "sessions": self.sessions,
                 "vocab": self.vocab, "carry_bytes": carry,
                 "n": int(self.plane["n"]), "k": int(self.plane["k"]),
-                "canary_k": int(self.plane["canary_k"])}
+                "canary_k": int(self.plane["canary_k"]),
+                **step_quantiles(np.diff(ends) * 1e3)}
 
     @staticmethod
     def end_to_end(facts: dict) -> dict:
@@ -120,11 +134,10 @@ class Driver:
         del self.pool, self.ring
 
     def _reference(self, k=None) -> dict:
-        n_steps = len(self.tokens)
-        toks, carry = self.ref.run(
+        return self.ref.run(
             self.cell.config, self.params, self.prompts[self.rows],
-            lambda t: self.logits[t % len(self.logits)], n_steps, k=k)
-        return {"tokens": toks, "carry": carry}
+            lambda t: self.logits[t % len(self.logits)], len(self.tokens),
+            k=k)
 
     def check(self) -> dict:
         return self.ref.compare(self.out, self._reference())

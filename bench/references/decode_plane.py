@@ -18,6 +18,17 @@ tables). Per session, with ``rotl`` an L-bit rotation:
   canary filter; the token is the first maximum of the masked logits
   (greedy), and the step consumes it.
 
+Every probe is computed modulo 2^32 and then masked to ``log2_m`` bits, and
+the low bits of a sum or a product modulo 2^32 depend only on the low bits
+of its operands: so every probe of ``h``, and whether ``h`` is present,
+depends only on ``h mod 2^log2_m``. A step therefore fills a table of
+membership over all ``2^log2_m`` residues with the direct test
+(``Plane._present``), once per run for the canary filter and once per step
+for each session's filter, and answers each candidate with one lookup
+(``present_table``, ``lookup``). The first step of every run also takes
+the direct test over every candidate and counts where the two forms
+differ (``table_mismatches``).
+
 The control is the same plane with half the no-repeat probes
 (``k // 2`` bits set and tested per n-gram): the cut that would tempt a
 faster plane, whose probes are its cost.
@@ -32,7 +43,8 @@ _U32 = np.uint32
 # sessions whose every token and final carry are compared, drawn from the seed
 CHECK_ROWS = 32
 # exact comparisons: no mismatch is allowed
-LIMITS = {"token_mismatches": 0, "carry_mismatch_rows": 0}
+LIMITS = {"token_mismatches": 0, "carry_mismatch_rows": 0,
+          "table_mismatches": 0}
 _STRIDE = _U32(0x9E3779B9)
 _NEG = np.float32(-1e30)
 CARRY = ("prefix", "ring", "pos", "bloom", "count", "active", "steps",
@@ -66,6 +78,30 @@ def _probes(h, k: int, log2_m: int):
     return [(h + _U32(i) * stride) & m for i in range(k)]
 
 
+def present_table(filt, k: int, log2_m: int, per_row: bool) -> np.ndarray:
+    """Membership of every residue ``r < 2^log2_m`` in ``filt``: a bool
+    table of ``2^log2_m`` entries, one row of them per filter row if
+    ``per_row``. ``h`` is present iff its table entry at ``h mod 2^log2_m``
+    is set (the module's docstring says why)."""
+    r = np.arange(1 << log2_m, dtype=_U32)
+    if per_row:
+        r = np.broadcast_to(r, (len(filt), r.size))
+    return Plane._present(r, filt, k, log2_m, per_row)
+
+
+def lookup(table: np.ndarray, prefix, h1, bits) -> np.ndarray:
+    """Whether the hash ``prefix[i] ^ h1[v]`` is present, for every row ``i``
+    and symbol ``v``, from :func:`present_table`'s table (of one filter for
+    every row, or one per row). Only the residue is formed, row by row:
+    ``(prefix[i] ^ h1[v]) & bits``, with ``bits`` the table's ``2^log2_m - 1``
+    and any mask the hash carries."""
+    low = (np.asarray(h1, _U32) & _U32(bits)).astype(np.intp)
+    out = np.empty((len(prefix), len(low)), bool)
+    for i, p in enumerate(np.asarray(prefix, _U32) & _U32(bits)):
+        out[i] = (table if table.ndim == 1 else table[i])[low ^ int(p)]
+    return out
+
+
 class Plane:
     """``rows`` sessions of the plane, stepped as the definitions say."""
 
@@ -78,6 +114,10 @@ class Plane:
         self.mask = _U32((1 << (self.L - self.n + 1)) - 1)
         self.h1 = np.asarray(h1, _U32) & _U32((1 << self.L) - 1)
         self.canary = np.asarray(canary, _U32)
+        self.canary_table = present_table(self.canary, self.canary_k,
+                                          self.canary_log2_m, False)
+        # set by the first step: where the table form and the direct differ
+        self.table_mismatches = None
         R = rows
         self.s = {"prefix": np.zeros(R, _U32),
                   "ring": np.zeros((R, self.n - 1), _U32),
@@ -123,16 +163,25 @@ class Plane:
     def step(self, logits) -> np.ndarray:
         """One greedy decode step over (rows, V) logits; returns tokens."""
         s = self.s
-        ready = (s["count"] >= self.n - 1)[:, None]
-        h = (_rotl(s["prefix"], 1, self.L)[:, None] ^ self.h1[None, :]) \
-            & self.mask
-        banned = self._present(h, s["bloom"], self.k, self.log2_m,
-                               True) & ready
-        canary = self._present(h, self.canary, self.canary_k,
-                               self.canary_log2_m, False) & ready
+        waiting = s["count"] < self.n - 1
+        prefix = _rotl(s["prefix"], 1, self.L)
+        bits = self.mask & _U32((1 << self.log2_m) - 1)
+        banned = lookup(present_table(s["bloom"], self.k, self.log2_m, True),
+                        prefix, self.h1, bits)
+        bits = self.mask & _U32((1 << self.canary_log2_m) - 1)
+        canary = lookup(self.canary_table, prefix, self.h1, bits)
+        if self.table_mismatches is None:
+            h = (prefix[:, None] ^ self.h1[None, :]) & self.mask
+            direct_b = self._present(h, s["bloom"], self.k, self.log2_m, True)
+            direct_c = self._present(h, self.canary, self.canary_k,
+                                     self.canary_log2_m, False)
+            self.table_mismatches = int((banned != direct_b).sum()
+                                        + (canary != direct_c).sum())
+        banned[waiting] = False
+        canary[waiting] = False
         tokens = np.argmax(np.where(banned, _NEG, logits), axis=1)
-        s["banned"] += banned.sum(axis=1, dtype=np.uint64)
-        s["canary"] += canary.sum(axis=1, dtype=np.uint64)
+        s["banned"] += np.count_nonzero(banned, axis=1).astype(np.uint64)
+        s["canary"] += np.count_nonzero(canary, axis=1).astype(np.uint64)
         s["steps"] += np.uint64(1)
         self.consume(tokens)
         return tokens.astype(np.int32)
@@ -156,20 +205,22 @@ def carry_rows(state: Dict[str, np.ndarray], rows) -> Dict[str, np.ndarray]:
 
 def run(cfg: dict, params, prompts, logits_at, n_steps: int, *, k=None):
     """Prime ``prompts`` (rows, T) and take ``n_steps`` greedy steps, step
-    ``t`` on ``logits_at(t)`` (rows, V). Returns (tokens (n_steps, rows),
-    final carry)."""
+    ``t`` on ``logits_at(t)`` (rows, V). Returns the ``tokens`` (n_steps,
+    rows), the final ``carry`` and the first step's ``table_mismatches``."""
     plane = Plane(cfg["decode_plane"], params["h1"], params["canary"],
                   prompts.shape[0], k=k)
     plane.prime(prompts)
     toks = np.stack([plane.step(logits_at(t)) for t in range(n_steps)])
-    return toks, plane.s
+    return {"tokens": toks, "carry": plane.s,
+            "table_mismatches": plane.table_mismatches}
 
 
 def compare(prog: dict, ref: dict) -> Dict[str, int]:
-    """Mismatch counts between a run's tokens and carry and the reference's.
+    """Mismatch counts between a run's tokens and carry and the reference's,
+    and the reference's own ``table_mismatches``.
 
     Each of ``prog`` and ``ref`` holds ``tokens`` (steps, rows) and
-    ``carry`` in the reference's layout.
+    ``carry`` in the reference's layout; ``ref`` is :func:`run`'s result.
     """
     rows = len(ref["carry"]["prefix"])
     bad = np.zeros(rows, bool)
@@ -177,4 +228,5 @@ def compare(prog: dict, ref: dict) -> Dict[str, int]:
         a, b = prog["carry"][key], ref["carry"][key]
         bad |= (a != b).reshape(rows, -1).any(axis=1)
     return {"token_mismatches": int((prog["tokens"] != ref["tokens"]).sum()),
-            "carry_mismatch_rows": int(bad.sum())}
+            "carry_mismatch_rows": int(bad.sum()),
+            "table_mismatches": ref["table_mismatches"]}

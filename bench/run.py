@@ -27,6 +27,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -87,6 +88,39 @@ class CompileCount:
             self.n += 1
 
 
+class GcCount:
+    """Python's garbage collections while it is registered: the count per
+    generation, their total milliseconds and the longest. A log line, not
+    a metric."""
+
+    def __init__(self):
+        self.counts = [0, 0, 0]
+        self.total_ms = self.max_ms = 0.0
+        self._t0 = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            ms = 1e3 * (time.perf_counter() - self._t0)
+            self.counts[info["generation"]] += 1
+            self.total_ms += ms
+            self.max_ms = max(self.max_ms, ms)
+            self._t0 = None
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+    def summary(self) -> dict:
+        return {"gen0": self.counts[0], "gen1": self.counts[1],
+                "gen2": self.counts[2], "total_ms": self.total_ms,
+                "max_ms": self.max_ms}
+
+
 def _profile_options():
     import jax
     opts = jax.profiler.ProfileOptions()
@@ -110,13 +144,14 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
         jax.profiler.start_trace(str(TRACE_DIR),
                                  profiler_options=_profile_options())
     try:
-        with spans.span(tracing.WINDOW):
+        with GcCount() as collections, spans.span(tracing.WINDOW):
             facts = driver.window(seconds, spans)
     finally:
         if trace:
             jax.profiler.stop_trace()
     if compiles:
         log.append(f"compiles_in_window={compiles.n - c0}")
+    log.append("gc_in_window=" + json.dumps(collections.summary()))
     summary = None
     if trace:
         summary = tracing.summarize(tracing.extract(
