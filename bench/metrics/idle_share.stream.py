@@ -1,0 +1,11 @@
+"""Share of the traced window in which no operation ran on the device.
+
+One minus the union of the device's operation intervals over the window
+(``bench/trace.py``), averaged over the chips used.
+"""
+
+
+def read(facts, trace, peaks):
+    if trace is None or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])

@@ -46,7 +46,8 @@ def main(argv=None) -> int:
         static = discard.static_findings()
         for f in static:
             print(f)
-        trace = discard.verify_decode_discard()
+        trace = discard.verify_decode_discard() + \
+            discard.verify_stats_discard()
         for f in trace:
             print(f)
         failures += len(static) + len(trace)

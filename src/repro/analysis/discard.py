@@ -30,7 +30,11 @@ recursion (xor/rotate/select — full-width state is the recursion's
 contract) but must never feed a probe-shaped consumer (multiply/add for the
 double-hashing stride, shifts for word indices, gathers for filter lookups).
 :func:`verify_decode_discard` drives this over the decode plane's actual
-traces (fused + oracle + session step), where Theorem 2 is load-bearing.
+traces (fused + oracle + session step), where Theorem 2 is load-bearing,
+and :func:`verify_stats_discard` over the statistics plan at two-word
+lanes: there the discard keeps the low word whole and masks the high word
+to ``L - n + 1 - 32`` bits, so the high word's mask is the discard site
+whose raw operand no HLL rank or CountMin piece may read.
 
 Both halves return findings (empty = the discard holds); the
 ``python -m repro.analysis`` driver folds them into the repo-wide report.
@@ -45,7 +49,8 @@ from typing import Dict, List, Optional
 from repro.analysis import jaxpr as jxa
 
 __all__ = ["DiscardFinding", "static_findings", "trace_findings",
-           "verify_decode_discard", "SCOPE", "PROBE_CALLEES"]
+           "verify_decode_discard", "verify_stats_discard", "SCOPE",
+           "PROBE_CALLEES"]
 
 # the consumer layers Theorems 1-2 bind (the hash *producers* in kernels/
 # legitimately hold full-width state for the recursion)
@@ -304,4 +309,35 @@ def verify_decode_discard(spec=None) -> List[DiscardFinding]:
             spec, False, None, (), 0.8, 5, st, lg, h, cb, k, tt))(
         state, logits, h1, key, t)
     check("SessionPool.step", jx)
+    return findings
+
+
+def verify_stats_discard(n: int = 13, L: int = 64, b: int = 14,
+                         log2_width: int = 20) -> List[DiscardFinding]:
+    """Trace the statistics plan (HLL + CountMin, both scatter epilogues)
+    at two-word lanes, through the Pallas kernel and the jnp oracle, and
+    run :func:`trace_findings` with the high word's mask: the dependent
+    ``n - 1`` bits are the top of the high word, and no rank or column may
+    be derived from them."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import api
+    from repro.kernels.plan import (CountMinSpec, HashSpec, HLLSpec,
+                                    SketchPlan)
+
+    hs = HashSpec(family="cyclic", n=n, L=L)
+    if hs.words != 2 or hs.word_masks[1] == (1 << (L - 32)) - 1:
+        return []
+    cms = CountMinSpec(depth=2, log2_width=log2_width)
+    plan = SketchPlan(hs, (("hll", HLLSpec(b=b)), ("cms", cms)))
+    h1v = jnp.zeros((2, 2, 64), jnp.uint32)
+    ops = {"cms": {"a": jnp.ones((2, cms.key_pieces(hs)), jnp.uint32),
+                   "b": jnp.zeros((2,), jnp.uint32)}}
+    findings: List[DiscardFinding] = []
+    for impl in ("pallas", "ref"):
+        jx = jax.make_jaxpr(
+            lambda x, o: api.run(plan, x, operands=o, impl=impl))(h1v, ops)
+        for msg in trace_findings(jx, hs.word_masks[1]):
+            findings.append(DiscardFinding(
+                "trace", f"<stats plan L={L} impl={impl}>", 0, msg))
     return findings

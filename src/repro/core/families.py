@@ -1,7 +1,9 @@
 """The paper's recursive n-gram hash families, in three evaluation forms.
 
 Every family hashes all length-``n`` windows of a token stream to ``L``-bit
-values (uint32 lanes). Three mathematically identical evaluation forms are
+values (uint32 lanes). CYCLIC also runs at ``32 < L <= 64``: a lane is then
+two uint32 words (x64 stays off) and every lane array carries a leading
+word axis, ``(2, ...)`` with the low word first. Three mathematically identical evaluation forms are
 provided per family:
 
 * ``hash_stream``   — the paper's character-at-a-time *recursive* algorithm
@@ -46,15 +48,25 @@ def _as_u32(tokens) -> jnp.ndarray:
     return jnp.asarray(tokens).astype(_U32)
 
 
-def init_h1(key, sigma: int) -> jnp.ndarray:
-    """Fully independent symbol hash: one i.i.d. uniform uint32 per symbol."""
-    return jax.random.bits(key, (sigma,), dtype=_U32)
+def init_h1(key, sigma: int, words: int = 1) -> jnp.ndarray:
+    """Fully independent symbol hash: i.i.d. uniform uint32 words per
+    symbol, ``(sigma,)`` for one-word lanes and ``(words, sigma)`` else."""
+    shape = (sigma,) if words == 1 else (words, sigma)
+    return jax.random.bits(key, shape, dtype=_U32)
+
+
+def _word_masks(L: int) -> np.ndarray:
+    """Per-word keep masks of an L-bit two-word lane, shaped to broadcast
+    over the leading word axis."""
+    return np.array([0xFFFFFFFF, gf2.mask(L - 32)], np.uint32)
 
 
 @dataclasses.dataclass(frozen=True)
 class _Family:
     n: int
     L: int = 32
+
+    MAX_L = 32          # CYCLIC alone takes two-word lanes
 
     @property
     def name(self) -> str:
@@ -64,9 +76,14 @@ class _Family:
     def out_bits(self) -> int:
         return self.L
 
+    @property
+    def words(self) -> int:
+        """uint32 words per lane: 1 up to L = 32, else 2."""
+        return 1 if self.L <= 32 else 2
+
     def __post_init__(self):
-        if not 1 <= self.L <= 32:
-            raise ValueError("L must be in [1, 32]")
+        if not 1 <= self.L <= self.MAX_L:
+            raise ValueError(f"L must be in [1, {self.MAX_L}]")
         if self.n < 1:
             raise ValueError("n must be >= 1")
 
@@ -75,26 +92,33 @@ class _Family:
         return np.uint32(gf2.mask(self.L))
 
     def _lookup(self, params: Params, tokens) -> jnp.ndarray:
-        return params["h1"][_as_u32(tokens)] & self._mask()
+        if self.words == 1:
+            return params["h1"][_as_u32(tokens)] & self._mask()
+        t = _as_u32(tokens)
+        masks = _word_masks(self.L).reshape((2,) + (1,) * t.ndim)
+        return params["h1"][:, t] & masks
 
     def init(self, key, sigma: int) -> Params:
-        return {"h1": init_h1(key, sigma)}
+        return {"h1": init_h1(key, sigma, self.words)}
 
     def hash_ngram(self, params: Params, ngram) -> jnp.ndarray:
-        """Hash a single n-gram (length-n token array) -> scalar uint32."""
+        """Hash a single n-gram (length-n token array) -> scalar uint32
+        (its (2,) words for two-word lanes)."""
         out = self.hash_windows_direct(params, ngram)
-        return out[0]
+        return out[..., 0]
 
     def hash_windows(self, params: Params, tokens) -> jnp.ndarray:
         return self.hash_windows_direct(params, tokens)
 
     def hash_windows_batched(self, params: Params, tokens) -> jnp.ndarray:
-        """tokens: (..., S) -> (..., S-n+1); vmaps over leading dims."""
+        """tokens: (..., S) -> (..., S-n+1); vmaps over leading dims.
+        Two-word lanes come back as (2, ..., S-n+1)."""
         fn = self.hash_windows
         t = _as_u32(tokens)
         for _ in range(t.ndim - 1):
             fn = jax.vmap(fn, in_axes=(None, 0))
-        return fn(params, t)
+        out = fn(params, t)
+        return out if self.words == 1 else jnp.moveaxis(out, -2, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +338,12 @@ class BufferedGeneral(General):
 @dataclasses.dataclass(frozen=True)
 class Cyclic(_Family):
     """Rotation-based rolling hash. Not uniform on all L bits (Lemma 3), but
-    pairwise independent on any L-n+1 consecutive bits (Theorem 1)."""
+    pairwise independent on any L-n+1 consecutive bits (Theorem 1).
+
+    Takes ``L`` up to 64: x^L + 1 is the modulus at any width, and past 32
+    bits a lane is two uint32 words (hashes come back as (2, W))."""
+
+    MAX_L = 64
 
     def __post_init__(self):
         super().__post_init__()
@@ -330,16 +359,46 @@ class Cyclic(_Family):
         t = _as_u32(tokens)
         W = t.shape[-1] - self.n + 1
         h1v = self._lookup(params, t)
+        if self.words == 2:
+            return self._direct_words(h1v, W)
         acc = jnp.zeros((W,), dtype=_U32)
         for k in range(self.n):
             acc = acc ^ gf2.rotl(h1v[k : k + W], (self.n - 1 - k) % self.L, self.L)
         return acc
+
+    def _direct_words(self, h1v: jnp.ndarray, W: int) -> jnp.ndarray:
+        """The window formula on (2, S) two-word lanes -> (2, W)."""
+        lo = jnp.zeros((W,), dtype=_U32)
+        hi = jnp.zeros((W,), dtype=_U32)
+        for k in range(self.n):
+            rl, rh = gf2.rotl_words(h1v[0, k : k + W], h1v[1, k : k + W],
+                                    self.n - 1 - k, self.L)
+            lo, hi = lo ^ rl, hi ^ rh
+        return jnp.stack([lo, hi])
+
+    def _stream_words(self, h1v: jnp.ndarray) -> jnp.ndarray:
+        """Algorithm 4 on (2, S) two-word lanes -> (2, S-n+1)."""
+        n, L = self.n, self.L
+        lag = (jnp.concatenate([jnp.zeros((2, n), _U32), h1v[:, :-n]], axis=1)
+               if h1v.shape[-1] > n else jnp.zeros_like(h1v))
+
+        def step(x, inp):
+            c, z = inp
+            xl, xh = gf2.rotl_words(x[0], x[1], 1, L)
+            zl, zh = gf2.rotl_words(z[0], z[1], n, L)
+            x = jnp.stack([xl ^ zl ^ c[0], xh ^ zh ^ c[1]])
+            return x, x
+
+        _, xs = jax.lax.scan(step, jnp.zeros((2,), _U32), (h1v.T, lag.T))
+        return xs[n - 1 :].T
 
     def hash_stream(self, params: Params, tokens) -> jnp.ndarray:
         # Algorithm 4: rotate x by 1, rotate z by n, x <- x XOR z XOR h1(c).
         t = _as_u32(tokens)
         n = self.n
         h1v = self._lookup(params, t)
+        if self.words == 2:
+            return self._stream_words(h1v)
         lag = jnp.concatenate([jnp.zeros((n,), dtype=_U32), h1v[:-n]]) if t.shape[-1] > n \
             else jnp.zeros_like(h1v)
 
@@ -359,6 +418,8 @@ class Cyclic(_Family):
         inverse, so the sliding window collapses to two prefix lookups; the
         prefix itself is an associative scan (O(log S) depth on TPU).
         """
+        if self.words == 2:       # per-lane rotations are one-word ops
+            return self.hash_windows_direct(params, tokens)
         t = _as_u32(tokens)
         S = t.shape[-1]
         n, L, W = self.n, self.L, t.shape[-1] - self.n + 1
@@ -373,7 +434,15 @@ class Cyclic(_Family):
 
     def pairwise_bits(self, h: jnp.ndarray, *, keep_low: bool = True) -> jnp.ndarray:
         """Discard n-1 consecutive bits (Theorem 1) -> pairwise-independent
-        (L-n+1)-bit values. ``keep_low`` keeps bits [0, L-n+1)."""
+        (L-n+1)-bit values. ``keep_low`` keeps bits [0, L-n+1). Two-word
+        hashes (2, ...) keep their word axis."""
+        if self.words == 2:
+            lo, hi = h[0], h[1]
+            if not keep_low:
+                lo, hi = gf2.shr_words(lo, hi, self.n - 1)
+            keep = (1 << self.out_bits) - 1
+            return jnp.stack([lo & np.uint32(keep & 0xFFFFFFFF),
+                              hi & np.uint32(keep >> 32)])
         if keep_low:
             return h & np.uint32(gf2.mask(self.out_bits))
         return (h >> np.uint32(self.n - 1)) & np.uint32(gf2.mask(self.out_bits))

@@ -36,6 +36,9 @@ __all__ = [
     "find_irreducible_host",
     "rotl",
     "rotr",
+    "rotl_words",
+    "shl_words",
+    "shr_words",
     "PAPER_TABLE2",
     "PAPER_GENERAL_L19_AS_PRINTED",
     "GENERAL_L19",
@@ -226,6 +229,38 @@ def rotl(v: jnp.ndarray, r, L: int) -> jnp.ndarray:
 def rotr(v: jnp.ndarray, r, L: int) -> jnp.ndarray:
     r = jnp.asarray(r, dtype=_U32) % np.uint32(L)
     return rotl(v, (np.uint32(L) - r) % np.uint32(L), L)
+
+
+def shl_words(lo, hi, s: int):
+    """The two-word value hi:lo shifted left by a static ``0 <= s < 64``."""
+    if s == 0:
+        return lo, hi
+    if s >= 32:
+        return jnp.zeros_like(lo), lo << np.uint32(s - 32)
+    return lo << np.uint32(s), (hi << np.uint32(s)) | (lo >> np.uint32(32 - s))
+
+
+def shr_words(lo, hi, s: int):
+    """The two-word value hi:lo shifted right by a static ``0 <= s < 64``."""
+    if s == 0:
+        return lo, hi
+    if s >= 32:
+        return hi >> np.uint32(s - 32), jnp.zeros_like(hi)
+    return (lo >> np.uint32(s)) | (hi << np.uint32(32 - s)), hi >> np.uint32(s)
+
+
+def rotl_words(lo: jnp.ndarray, hi: jnp.ndarray, r: int, L: int):
+    """Rotate-left the L-bit value ``hi:lo`` (``32 < L <= 64``, two uint32
+    words, low word first) by a static ``r``; x^L + 1 is CYCLIC's modulus
+    at any width, so this is multiplication by x^r in its ring."""
+    r %= L
+    hm = np.uint32(mask(L - 32))
+    lo, hi = lo.astype(_U32), hi.astype(_U32) & hm
+    if r == 0:
+        return lo, hi
+    a_lo, a_hi = shl_words(lo, hi, r)
+    b_lo, b_hi = shr_words(lo, hi, L - r)
+    return a_lo | b_lo, (a_hi | b_hi) & hm
 
 
 def build_shiftn_table_host(n: int, p: int, L: int, k_split: int = 1) -> list[np.ndarray]:

@@ -19,6 +19,20 @@ hash graph, so query columns can never drift from update columns.
 The token counter accumulates as a uint32 (lo, hi) pair: a plain int32
 counter wraps negative at ~2.1B tokens — a few hours of production traffic
 — and jnp.int64 silently downcasts when x64 is off, so neither is safe.
+
+Long n-grams want wide lanes: at ``L = 32`` and ``n = 13`` the Theorem-1
+discard leaves 20 usable bits, so HLL and CountMin could tell at most 2^20
+distinct 13-grams apart. CYCLIC runs up to ``L = 64`` (52 usable bits at
+n = 13) on two uint32 words per lane, decided by ``L`` alone: the h1
+table, the window hashes and the stream's tail then carry a leading word
+axis, HLL ranks span both words, and CountMin takes the vector
+multiply-add-shift over the key's pieces.
+
+Observability (``repro.obs``): the span ``stats.update_stream`` covers each
+:meth:`NgramStats.update_stream_many` call and the counter ``stats.tokens``
+counts the tokens every update folds; on the device, ``stats.lookup``
+names the h1 lookup (the plan's scatter epilogues are
+``sketch.hll.scatter`` and ``sketch.cms.scatter``).
 """
 from __future__ import annotations
 
@@ -29,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import CountMinSketch, HyperLogLog, make_family
 from repro.kernels import ops, shard, stream
 from repro.kernels.plan import CountMinSpec, HashSpec, HLLSpec, SketchPlan
@@ -71,9 +86,12 @@ class NgramStats:
         self.fam = make_family(cfg.family, n=cfg.ngram_n, L=cfg.L)
         self.fp = self.fam.init(kf, cfg.vocab)
         self.hll = HyperLogLog(b=cfg.hll_b,
-                               hash_bits=self.fam.out_bits)
+                               hash_bits=self.fam.out_bits,
+                               words=self.fam.words)
         self.cms = CountMinSketch(depth=cfg.cms_depth,
-                                  log2_width=cfg.cms_log2_width)
+                                  log2_width=cfg.cms_log2_width,
+                                  key_bits=self.fam.out_bits,
+                                  words=self.fam.words)
         self._cms_params = self.cms.init(kc)
         # the fused HLL+CMS plan, built ONCE (hoisted out of the per-batch
         # update; it is the jit trace key). One plan execution per batch is
@@ -91,7 +109,11 @@ class NgramStats:
             assert self.plan.hash.out_bits == self.hll.hash_bits, (
                 self.plan.hash.out_bits, self.hll.hash_bits)
         self._update = jax.jit(self._update_impl)
-        self._lookup = jax.jit(lambda t: self.fam._lookup(self.fp, t))
+        self._lookup = jax.jit(self._lookup_impl)
+
+    def _lookup_impl(self, tokens):
+        with jax.named_scope("stats.lookup"):
+            return self.fam._lookup(self.fp, tokens)
 
     def init_state(self) -> Dict:
         # token counter: uint32 (lo, hi) pair — int32 wraps negative at
@@ -125,7 +147,7 @@ class NgramStats:
         if self.plan is not None:
             # ONE fused pass: rolling hash + discard + HLL register max +
             # CountMin histogram, all from the same plan execution
-            h1v = self.fam._lookup(self.fp, tokens)
+            h1v = self._lookup_impl(tokens)
             out = shard.run_auto(
                 self.plan, h1v,
                 operands={"cms": {"a": self._cms_params["a"],
@@ -143,7 +165,9 @@ class NgramStats:
                 "tokens": self._count_tokens(state["tokens"], tokens.size)}
 
     def update(self, state: Dict, tokens: jnp.ndarray) -> Dict:
-        return self._update(state, jnp.asarray(tokens, jnp.uint32))
+        tokens = jnp.asarray(tokens, jnp.uint32)
+        obs.count("stats.tokens", int(tokens.size))
+        return self._update(state, tokens)
 
     # -- true streaming (unbounded token streams, fixed chunk shape) --------
 
@@ -185,6 +209,7 @@ class NgramStats:
             data_shards=self.cfg.data_shards)
         added = (int(tokens.shape[0]) * int(tokens.shape[1])
                  if lengths is None else int(np.sum(np.asarray(lengths))))
+        obs.count("stats.tokens", added)
         return {**sstate, "stream": st,
                 "tokens": self._count_tokens(sstate["tokens"], added)}
 
@@ -193,17 +218,20 @@ class NgramStats:
         dispatch (the scan executor: the chunk loop runs as ``lax.scan``
         inside the compiled graph with the sketch state as the loop carry).
         Bit-identical to T successive :meth:`update_stream` calls, at
-        1/T of the dispatch overhead; a fixed block shape never retraces."""
-        tokens = jnp.asarray(tokens, jnp.uint32)
-        st = stream.update_many(
-            self.plan, sstate["stream"], self._lookup(tokens),
-            lengths=lengths,
-            operands={"cms": {"a": self._cms_params["a"],
-                              "b": self._cms_params["b"]}},
-            impl=self.cfg.impl, mesh=self.mesh,
-            data_shards=self.cfg.data_shards)
-        added = (int(tokens.size)
-                 if lengths is None else int(np.sum(np.asarray(lengths))))
+        1/T of the dispatch overhead; a fixed block shape never retraces.
+        Recorded as the span ``stats.update_stream``."""
+        with obs.span("stats.update_stream"):
+            tokens = jnp.asarray(tokens, jnp.uint32)
+            st = stream.update_many(
+                self.plan, sstate["stream"], self._lookup(tokens),
+                lengths=lengths,
+                operands={"cms": {"a": self._cms_params["a"],
+                                  "b": self._cms_params["b"]}},
+                impl=self.cfg.impl, mesh=self.mesh,
+                data_shards=self.cfg.data_shards)
+            added = (int(tokens.size) if lengths is None
+                     else int(np.sum(np.asarray(lengths))))
+            obs.count("stats.tokens", added)
         return {**sstate, "stream": st,
                 "tokens": self._count_tokens(sstate["tokens"], added)}
 
@@ -242,7 +270,7 @@ class NgramStats:
         self.fp = jax.tree_util.tree_map(jnp.asarray, params["fam"])
         self._cms_params = jax.tree_util.tree_map(jnp.asarray, params["cms"])
         self._update = jax.jit(self._update_impl)
-        self._lookup = jax.jit(lambda t: self.fam._lookup(self.fp, t))
+        self._lookup = jax.jit(self._lookup_impl)
 
     def import_stream(self, tree: Dict) -> Dict:
         """Rebuild a live stream state from :meth:`export_stream`'s tree on
@@ -264,7 +292,8 @@ class NgramStats:
         """(..., S) tokens -> (..., S-n+1) masked window hashes on the SAME
         graph the fused update feeds to CountMin — the query side of the
         sketch must remix bit-identical hashes or frequency estimates
-        silently corrupt (asserted in tests/test_data.py)."""
+        silently corrupt (asserted in tests/test_data.py). Two-word lanes
+        come back as (2, ..., S-n+1)."""
         if self.plan is not None:
             h1v = self.fam._lookup(self.fp, tokens)
             hs = self.plan.hash
@@ -273,6 +302,9 @@ class NgramStats:
             else:
                 h = ops.general(h1v, n=hs.n, p=hs.p, L=hs.L,
                                 impl=self.cfg.impl)
+            if hs.words == 2:
+                masks = np.array(hs.word_masks, np.uint32)
+                return h & masks.reshape((2,) + (1,) * (h.ndim - 1))
             return h & np.uint32(hs.hash_mask)
         return self._unfused_hashes(tokens)
 

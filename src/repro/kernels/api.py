@@ -68,13 +68,26 @@ def use_ref(impl: str) -> bool:
     return impl == "ref" or (impl == "auto" and not on_tpu())
 
 
-def flatten(x: jnp.ndarray):
-    """(..., S) -> ((B, S), leading-shape) for batch tiling."""
-    lead = x.shape[:-1]
-    return x.reshape((-1, x.shape[-1])), lead
+def flatten(x: jnp.ndarray, words: int = 1):
+    """(..., S) -> ((B, S), leading-shape) for batch tiling; two-word lanes
+    (2, ..., S) -> ((2, B, S), leading-shape)."""
+    if words == 1:
+        lead = x.shape[:-1]
+        return x.reshape((-1, x.shape[-1])), lead
+    if x.ndim < 2 or x.shape[0] != words:
+        raise ValueError(f"two-word lanes are ({words}, ..., S) uint32 word "
+                         f"planes, low word first; got shape {x.shape}")
+    lead = x.shape[1:-1]
+    return x.reshape((words, -1, x.shape[-1])), lead
 
 
-def prepare(h1v: jnp.ndarray, *, n: int, impl: str, allow_short: bool = False):
+def lead_shape(out: jnp.ndarray, lead, words: int = 1):
+    """(B, W) or (2, B, W) -> the caller's (..., W) or (2, ..., W)."""
+    return out.reshape(out.shape[:words - 1] + lead + (out.shape[-1],))
+
+
+def prepare(h1v: jnp.ndarray, *, n: int, impl: str, allow_short: bool = False,
+            words: int = 1):
     """The one validated prologue every kernel entry point shares: flatten
     leading dims, check the window fits, resolve the impl dispatch.
 
@@ -85,14 +98,17 @@ def prepare(h1v: jnp.ndarray, *, n: int, impl: str, allow_short: bool = False):
     plain-hash entry points keep the hard error: their *output* is the
     window-hash array, which has no rows to return when S < n.
 
-    Returns (x (B, max(S, n)), lead shape, use_ref flag)."""
+    ``words=2`` takes two-word lanes (2, ..., S) (``HashSpec.words``).
+
+    Returns (x (B, max(S, n)) — (2, B, max(S, n)) for two-word lanes —,
+    lead shape, use_ref flag)."""
     ref_path = use_ref(impl)        # validates impl before any shape work
-    x, lead = flatten(jnp.asarray(h1v))
+    x, lead = flatten(jnp.asarray(h1v), words)
     S = x.shape[-1]
     if S < n:
         if not allow_short:
             raise ValueError(f"sequence length {S} < window n={n}")
-        x = jnp.pad(x, ((0, 0), (0, n - S)))
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, n - S),))
     return x, lead, ref_path
 
 
@@ -190,11 +206,18 @@ def _check_operands(plan: SketchPlan, operands,
                     f"{got['bits'].shape} != ({spec.n_words},) for "
                     f"log2_m={spec.log2_m}")
         elif isinstance(spec, CountMinSpec):
+            # over two-word hashes ``a`` holds one multiplier per key piece
+            pieces = spec.key_pieces(plan.hash)
             for op in ("a", "b"):
-                if got[op].shape != (spec.depth,):
+                if op == "a" and pieces:
+                    want, text = (spec.depth, pieces), (
+                        f"(depth={spec.depth}, pieces={pieces})")
+                else:
+                    want, text = (spec.depth,), f"(depth={spec.depth},)"
+                if got[op].shape != want:
                     raise ValueError(
                         f"sketch {name!r}: operand {op!r} shape "
-                        f"{got[op].shape} != (depth={spec.depth},)")
+                        f"{got[op].shape} != {text}")
         operands[name] = got
     return operands
 
@@ -226,8 +249,9 @@ def validate(plan: SketchPlan, h1v, h1v_b, n_windows, operands, impl: str,
     n = plan.hash.n
     h1v = jnp.asarray(h1v)
     S0 = h1v.shape[-1]
-    x, lead, ref_path = prepare(h1v, n=n, impl=impl, allow_short=True)
-    B, S = x.shape
+    x, lead, ref_path = prepare(h1v, n=n, impl=impl, allow_short=True,
+                                words=plan.hash.words)
+    B, S = x.shape[-2:]
     operands = _check_operands(plan, operands, B)
     xb = None
     if plan.needs_second_stream:
@@ -354,7 +378,8 @@ def run(plan: SketchPlan, h1v: jnp.ndarray, *, h1v_b=None, n_windows=None,
       plan: hash family + named sketch specs (static; one compiled executor
         per distinct plan).
       h1v: (..., S) uint32 h1-mapped token values; leading dims are
-        flattened to a batch and restored on return.
+        flattened to a batch and restored on return. Past ``L = 32`` the
+        lanes are two words with a leading word axis, (2, ..., S).
       h1v_b: second independent family draw, required iff the plan contains
         a :class:`BloomSpec` (double-hashing probe stride).
       n_windows: optional (...,) per-row valid-window counts for padded

@@ -13,6 +13,12 @@ Tiling: the sequence axis is cut into ``block_s`` chunks; each grid step loads
 its chunk plus an (n-1)-element halo from the *next* chunk — expressed as a
 second BlockSpec view of the same operand, offset by one block — into VMEM.
 All compute is uint32 bitwise ops on VMEM tiles.
+
+Past ``L = 32`` a lane is two uint32 words (x64 stays off): the input and
+output carry a leading word axis, ``(2, B, S)`` low word first, the blocks
+are ``(2, block_b, block_s)``, and the window formula rotates word pairs
+(``core.gf2.rotl_words``). The word count is static in ``L``: one-word lanes
+trace exactly the one-word code.
 """
 from __future__ import annotations
 
@@ -23,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.gf2 import rotl_words
 
 _U32 = jnp.uint32
 
@@ -44,6 +52,31 @@ def _rotl_var(v, r, L: int):
     left = (v << r) & m
     right = jnp.where(r == 0, jnp.zeros_like(v), (v & m) >> (np.uint32(L) - r))
     return left | right
+
+
+def _window_words(lo, hi, halo_lo, halo_hi, *, n: int, L: int,
+                  block_s: int):
+    """The direct window formula on (block_b, block_s) two-word tiles, the
+    next tile's first n - 1 columns as halo: XOR_k rotl(h1[j + k], n-1-k)
+    on word pairs. Returns the (lo, hi) hash tiles."""
+    shape = lo.shape
+    if n > 1:
+        lo = jnp.concatenate([lo, halo_lo[:, : n - 1]], axis=1)
+        hi = jnp.concatenate([hi, halo_hi[:, : n - 1]], axis=1)
+    acc_lo = jnp.zeros(shape, _U32)
+    acc_hi = jnp.zeros(shape, _U32)
+    for k in range(n):
+        rl, rh = rotl_words(lo[:, k : k + block_s], hi[:, k : k + block_s],
+                            n - 1 - k, L)
+        acc_lo, acc_hi = acc_lo ^ rl, acc_hi ^ rh
+    return acc_lo, acc_hi
+
+
+def _cyclic_words_kernel(x_ref, nxt_ref, o_ref, *, n: int, L: int,
+                         block_s: int):
+    """:func:`_window_words` on (2, block_b, block_s) blocks."""
+    o_ref[0], o_ref[1] = _window_words(x_ref[0], x_ref[1], nxt_ref[0],
+                                       nxt_ref[1], n=n, L=L, block_s=block_s)
 
 
 def _cyclic_kernel(x_ref, nxt_ref, o_ref, *, n: int, L: int, block_s: int,
@@ -87,11 +120,16 @@ def _cyclic_kernel(x_ref, nxt_ref, o_ref, *, n: int, L: int, block_s: int,
 def cyclic_rolling(h1v: jnp.ndarray, *, n: int, L: int = 32,
                    block_b: int = 8, block_s: int = 2048,
                    mode: str = "auto", interpret: bool = False) -> jnp.ndarray:
-    """Rolling CYCLIC hash of every n-window. (B, S) uint32 -> (B, S-n+1).
+    """Rolling CYCLIC hash of every n-window. (B, S) uint32 -> (B, S-n+1);
+    past L = 32, (2, B, S) two-word lanes -> (2, B, S-n+1).
 
     ``mode='auto'`` picks ``direct`` for small n and ``prefix`` once the
-    window outgrows the scan depth (n > log2(block_s)+4).
+    window outgrows the scan depth (n > log2(block_s)+4). Two-word lanes
+    take ``direct`` only (the prefix form rotates by per-lane amounts).
     """
+    if L > 32:
+        return _cyclic_words(h1v, n=n, L=L, block_b=block_b, block_s=block_s,
+                             mode=mode, interpret=interpret)
     assert h1v.ndim == 2, "use ops.cyclic (handles reshaping)"
     B, S = h1v.shape
     if mode == "auto":
@@ -124,3 +162,39 @@ def cyclic_rolling(h1v: jnp.ndarray, *, n: int, L: int = 32,
         interpret=interpret,
     )(x, x)
     return out[:B, : S - n + 1]
+
+
+def _cyclic_words(h1v, *, n: int, L: int, block_b: int, block_s: int,
+                  mode: str, interpret: bool):
+    """:func:`cyclic_rolling` on (2, B, S) two-word lanes."""
+    assert h1v.ndim == 3 and h1v.shape[0] == 2, \
+        "two-word lanes are (2, B, S); use ops.cyclic (handles reshaping)"
+    if mode not in ("auto", "direct"):
+        raise ValueError(f"two-word lanes (L={L}) take mode='direct', "
+                         f"got {mode!r}")
+    _, B, S = h1v.shape
+    block_s = min(block_s, max(256, 1 << int(np.ceil(np.log2(max(S, 1))))))
+    if n - 1 > block_s:
+        raise ValueError(f"halo n-1={n-1} exceeds block_s={block_s}")
+    Bp = -(-B // block_b) * block_b
+    Sp = -(-S // block_s) * block_s
+    x = jnp.pad(h1v.astype(_U32), ((0, 0), (0, Bp - B), (0, Sp - S)))
+    grid = (Bp // block_b, Sp // block_s)
+    nsb = grid[1]
+    blk = (2, block_b, block_s)
+    out = pl.pallas_call(
+        functools.partial(_cyclic_words_kernel, n=n, L=L, block_s=block_s),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec(blk, lambda b, j: (0, b, j),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(blk,
+                         lambda b, j, _n=nsb: (0, b, jnp.minimum(j + 1, _n - 1)),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec(blk, lambda b, j: (0, b, j),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((2, Bp, Sp), _U32),
+        interpret=interpret,
+    )(x, x)
+    return out[:, :B, : S - n + 1]

@@ -41,14 +41,16 @@ from repro.kernels.plan import (BloomSpec, HashSpec, HLLSpec, MinHashSpec,
 
 def cyclic(h1v: jnp.ndarray, *, n: int, L: int = 32, impl: str = "auto",
            mode: str = "auto", **tile_kw) -> jnp.ndarray:
-    """Rolling CYCLIC hash of h1-mapped values. (..., S) -> (..., S-n+1)."""
-    x, lead, ref_path = api.prepare(h1v, n=n, impl=impl)
+    """Rolling CYCLIC hash of h1-mapped values. (..., S) -> (..., S-n+1);
+    past L = 32, two-word lanes (2, ..., S) -> (2, ..., S-n+1)."""
+    words = 1 if L <= 32 else 2
+    x, lead, ref_path = api.prepare(h1v, n=n, impl=impl, words=words)
     if ref_path:
         out = _ref.cyclic_ref(x, n, L)
     else:
         out = cyclic_rolling(x, n=n, L=L, mode=mode,
                              interpret=not api.on_tpu(), **tile_kw)
-    return out.reshape(lead + (out.shape[-1],))
+    return api.lead_shape(out, lead, words)
 
 
 def general(h1v: jnp.ndarray, *, n: int, p: int, L: int = 32,

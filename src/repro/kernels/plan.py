@@ -40,6 +40,7 @@ import dataclasses
 from typing import Mapping, Optional, Tuple, Union
 
 from repro.core import gf2
+from repro.core.sketches import cms_pieces
 
 FAMILIES = ("cyclic", "general")
 
@@ -53,6 +54,13 @@ class HashSpec:
     all L bits (pairwise independent as-is, Lemma 1). ``p=0`` auto-resolves
     the degree-L irreducible modulus for GENERAL and must stay 0 for CYCLIC
     (whose modulus is fixed at x^L + 1).
+
+    CYCLIC takes ``L`` up to 64: past 32 bits a lane is two uint32 words
+    (x64 stays off), and every lane array carries a leading word axis of
+    size :attr:`words` — ``(2, ..., S)``, low word first — from the h1
+    lookup to the sketch epilogues. GENERAL stays at ``L <= 32``: its
+    recursion is a degree-L carry-less multiply, which the engine does not
+    emulate in two words.
     """
 
     family: str = "cyclic"
@@ -65,8 +73,12 @@ class HashSpec:
         if self.family not in FAMILIES:
             raise ValueError(
                 f"unknown hash family {self.family!r}; expected one of {FAMILIES}")
-        if not 1 <= self.L <= 32:
-            raise ValueError(f"L must be in [1, 32], got {self.L}")
+        if not 1 <= self.L <= 64:
+            raise ValueError(f"L must be in [1, 64], got {self.L}")
+        if self.L > 32 and self.family != "cyclic":
+            raise ValueError(
+                f"{self.family.upper()} supports L <= 32 only (its recursion "
+                f"is a degree-L carry-less multiply); L={self.L} needs CYCLIC")
         if self.n < 1:
             raise ValueError(f"n must be >= 1, got {self.n}")
         if self.L < self.n:
@@ -100,6 +112,17 @@ class HashSpec:
         """Low-bit keep mask applied inline to every window hash."""
         return (1 << self.out_bits) - 1
 
+    @property
+    def words(self) -> int:
+        """uint32 words per lane: 1 up to L = 32, else 2."""
+        return 1 if self.L <= 32 else 2
+
+    @property
+    def word_masks(self) -> Tuple[int, ...]:
+        """:attr:`hash_mask` cut into per-word keep masks, low word first."""
+        return tuple((self.hash_mask >> (32 * w)) & 0xFFFFFFFF
+                     for w in range(self.words))
+
 
 @dataclasses.dataclass(frozen=True)
 class MinHashSpec:
@@ -123,17 +146,32 @@ class MinHashSpec:
         return (batch, self.k), "uint32", 0xFFFFFFFF
 
 
+# The HLL epilogue's split (HLLSpec.use_in_kernel), read at trace time. On a
+# TPU v5e at 1536 x 2060 two-word lanes the XLA scatter-max costs about 22.6
+# ms a call at any b, and the in-kernel walk 7.9 ms at 167,936 cells (L = 64,
+# n = 13, b = 12) and 23.8 ms at 638,976 (b = 14); the threshold sits below
+# where the two cross (benchmarks/hll_split.py times both).
+HLL_IN_KERNEL_MAX_CELLS = 1 << 19
+
+
 @dataclasses.dataclass(frozen=True)
 class HLLSpec:
     """2^b-register HyperLogLog; ``rank_bits=None`` defaults to the usable
-    bits left after index extraction (``HashSpec.out_bits - b``)."""
+    bits left after index extraction (``HashSpec.out_bits - b``).
+
+    The Pallas path keeps the registers in VMEM scratch while the
+    kernel's one-hot walk costs at most :data:`HLL_IN_KERNEL_MAX_CELLS`
+    cells a window, ``(rank_bits + 1) * 2^b``; a costlier plan (wide hashes
+    at production precision) emits its window hashes for an XLA
+    scatter-max inside the same jit graph (:meth:`use_in_kernel`). The
+    split is a function of the spec, so ref and kernel agree on it."""
 
     b: int = 12
     rank_bits: Optional[int] = None
 
     def __post_init__(self):
-        if self.b < 1:
-            raise ValueError(f"HLL b must be >= 1, got {self.b}")
+        if not 1 <= self.b <= 31:
+            raise ValueError(f"HLL b must be in [1, 31], got {self.b}")
 
     operand_names: Tuple[str, ...] = dataclasses.field(
         default=(), init=False, repr=False, compare=False)
@@ -153,6 +191,12 @@ class HLLSpec:
                 f"HLL b={self.b} leaves no rank bits: the hash provides only "
                 f"{hash_spec.out_bits} usable bits (Theorem-1 discard)")
         return rb
+
+    def use_in_kernel(self, hash_spec: HashSpec) -> bool:
+        """True when the Pallas path keeps the registers in VMEM scratch;
+        False when it emits window hashes for the XLA scatter-max."""
+        cells = (self.resolve_rank_bits(hash_spec) + 1) << self.b
+        return cells <= HLL_IN_KERNEL_MAX_CELLS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,7 +231,8 @@ class BloomSpec:
 @dataclasses.dataclass(frozen=True)
 class CountMinSpec:
     """depth x 2^log2_width CountMin histogram; needs runtime operands
-    ``a``/``b`` (depth,) — the per-row affine remix constants (odd ``a``).
+    ``a``/``b`` (depth,) — the per-row affine remix constants (odd ``a``);
+    over two-word hashes ``a`` is (depth, :meth:`key_pieces`).
 
     Counts are additive: the engine returns the *batch partial table*
     (depth, width) int32, which merges into running state by ``+`` and
@@ -234,6 +279,22 @@ class CountMinSpec:
         """True when the Pallas path histograms in VMEM scratch; False when
         it emits window hashes for the XLA scatter-add epilogue."""
         return self.log2_width <= self.in_kernel_max_log2_width
+
+    def key_pieces(self, hash_spec: HashSpec) -> int:
+        """Pieces the plan's masked hashes (``hash_spec.out_bits`` wide)
+        are cut into for their columns; 0 for one-word lanes.
+
+        A one-word key takes the multiply-shift ``(a*h + b) >> (32 - l)``
+        with scalar ``a``. A two-word key takes Dietzfelbinger's vector
+        multiply-add-shift ``(sum_i a_i x_i + b) mod 2^32 >> (32 - l)``
+        over its pieces ``x_i`` of ``33 - l`` bits, low piece first: with
+        32-bit words that is the widest piece that keeps the family
+        strongly universal (pairwise independent) onto ``l`` column bits,
+        and every bit of the key reaches the column. ``a`` is then
+        (depth, pieces). The form follows the lane's word count."""
+        if hash_spec.words == 1:
+            return 0
+        return cms_pieces(hash_spec.out_bits, self.log2_width)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -361,6 +422,11 @@ class SketchPlan:
                     f"{[t.__name__ for t in _SPEC_TYPES]}, got {type(spec)}")
             if isinstance(spec, HLLSpec):
                 spec.resolve_rank_bits(self.hash)   # raises if inconsistent
+            if self.hash.words > 1 and isinstance(spec, (MinHashSpec,
+                                                         BloomSpec)):
+                raise ValueError(
+                    f"sketch {name!r}: {type(spec).__name__} takes one-word "
+                    f"hashes; L={self.hash.L} plans take HLL and CountMin")
         object.__setattr__(self, "sketches", items)
 
     @property

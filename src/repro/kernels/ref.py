@@ -1,15 +1,25 @@
-"""Pure-jnp oracles for every Pallas kernel (independent of repro.core).
+"""Pure-jnp oracles for every Pallas kernel.
 
-These are deliberately naive re-implementations of the defining formulas —
-the kernels and `repro.core.families` are each validated against these, so a
-shared bug between kernel and library would still be caught by the paper's
-enumeration tests in `tests/test_independence.py`.
+The window-hash oracles are deliberately naive re-implementations of the
+defining formulas, independent of repro.core — the kernels and
+`repro.core.families` are each validated against these, so a shared bug
+between kernel and library would still be caught by the paper's
+enumeration tests in `tests/test_independence.py`. The HLL and CountMin
+epilogues take their key -> register and key -> column rules from
+`repro.core.sketches`, the one definition the query side and the kernel
+tiles share; tests check those rules against NumPy.
+
+Past ``L = 32`` a CYCLIC lane is two uint32 words: lane arrays carry a
+leading word axis ``(2, ...)``, low word first, and a window hash is the
+XOR of word-pair rotations (:func:`_rotl_pair`).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.sketches import cms_columns, hll_index_rank
 
 _U32 = jnp.uint32
 
@@ -23,8 +33,40 @@ def _rotl_const(v: jnp.ndarray, r: int, L: int) -> jnp.ndarray:
     return ((v << np.uint32(r)) | (v >> np.uint32(L - r))) & m
 
 
+def _rotl_pair(lo, hi, r: int, L: int):
+    """Rotate-left the L-bit value ``hi * 2^32 + lo`` (``32 < L <= 64``) by
+    a static ``r``, from the definition: bit ``i`` moves to bit
+    ``(i + r) mod L``, carried over in runs that stay inside one source
+    word and one target word."""
+    r %= L
+    out = [jnp.zeros_like(lo), jnp.zeros_like(hi)]
+    src = (lo, hi)
+    pos = 0
+    while pos < L:
+        src_word, src_off = divmod(pos, 32)
+        dst = (pos + r) % L
+        dst_word, dst_off = divmod(dst, 32)
+        width = min(32 - src_off, 32 - dst_off, L - pos, L - dst)
+        run = (src[src_word] >> np.uint32(src_off)) & np.uint32(
+            (1 << width) - 1)
+        out[dst_word] = out[dst_word] | (run << np.uint32(dst_off))
+        pos += width
+    return out[0], out[1]
+
+
 def cyclic_ref(h1v: jnp.ndarray, n: int, L: int = 32) -> jnp.ndarray:
-    """CYCLIC window hashes: H_j = XOR_k rotl(h1v[j+k], n-1-k). (..., S) -> (..., S-n+1)."""
+    """CYCLIC window hashes: H_j = XOR_k rotl(h1v[j+k], n-1-k). (..., S) -> (..., S-n+1).
+    Past L = 32 the lanes are two words: (2, ..., S) -> (2, ..., S-n+1)."""
+    if L > 32:
+        W = h1v.shape[-1] - n + 1
+        h1v = h1v.astype(_U32)
+        lo = jnp.zeros(h1v.shape[1:-1] + (W,), dtype=_U32)
+        hi = jnp.zeros_like(lo)
+        for k in range(n):
+            rl, rh = _rotl_pair(h1v[0, ..., k : k + W], h1v[1, ..., k : k + W],
+                                n - 1 - k, L)
+            lo, hi = lo ^ rl, hi ^ rh
+        return jnp.stack([lo, hi])
     S = h1v.shape[-1]
     W = S - n + 1
     acc = jnp.zeros(h1v.shape[:-1] + (W,), dtype=_U32)
@@ -96,13 +138,22 @@ def window_hashes_ref(h1v, *, family: str, n: int, L: int,
     raise ValueError(f"unknown hash family {family!r}")
 
 
+def _apply_mask(h, hash_mask: int, L: int):
+    """The discard: keep the low bits of ``hash_mask``, per word past L = 32."""
+    if L <= 32:
+        return h & np.uint32(hash_mask)
+    return jnp.stack([h[0] & np.uint32(hash_mask & 0xFFFFFFFF),
+                      h[1] & np.uint32(hash_mask >> 32)])
+
+
 def _masked_windows(h1v, n: int, L: int, hash_mask: int, n_windows,
                     family: str = "cyclic", p: int = 0, w_start=None):
     """(B, S) -> (B, W) window hashes with the discard mask applied and a
     (B, W) bool validity mask (``w_start <= global window index <
-    n_windows``; ``w_start=None`` means 0)."""
+    n_windows``; ``w_start=None`` means 0). Two-word lanes: (2, B, S) ->
+    (2, B, W) hashes and the same (B, W) mask."""
     h = window_hashes_ref(h1v, family=family, n=n, L=L, p=p)
-    h = h & np.uint32(hash_mask)
+    h = _apply_mask(h, hash_mask, L)
     idx = jnp.arange(h.shape[-1], dtype=jnp.int32)
     valid = idx[None, :] < n_windows.astype(jnp.int32)[:, None]
     if w_start is not None:
@@ -128,36 +179,43 @@ def minhash_reduce(h, valid, a, b, k_chunk: int = 16, init=None) -> jnp.ndarray:
     return out if init is None else jnp.minimum(out, init)
 
 
+def _wide(h, valid) -> bool:
+    """Two-word hashes carry a leading word axis over the validity shape."""
+    return h.ndim == valid.ndim + 1
+
+
 def _hll_reduce(h, valid, b: int, rank_bits: int, init=None) -> jnp.ndarray:
     """(B, W) masked hashes -> (2^b,) int32 registers over valid windows;
-    ``init`` optionally carries a register file in (merged by max)."""
-    h, valid = h.reshape(-1), valid.reshape(-1)
-    m = 1 << b
-    idx = (h & np.uint32(m - 1)).astype(jnp.int32)
-    rest = h >> np.uint32(b)
-    isolated = rest & (~rest + np.uint32(1))
-    tz = jax.lax.population_count(isolated - np.uint32(1))
-    rank = (jnp.minimum(tz, np.uint32(rank_bits)) + 1).astype(jnp.int32)
-    rank = jnp.where(valid, rank, 0)
-    out = jnp.zeros((m,), jnp.int32).at[idx].max(rank)
+    ``init`` optionally carries a register file in (merged by max). Two-word
+    hashes (2, B, W): the index is the low b bits of the low word, the rank
+    the trailing zeros of every bit above it
+    (:func:`repro.core.sketches.hll_index_rank`)."""
+    if _wide(h, valid):
+        hw = h.astype(_U32).reshape(2, -1)
+        key = (hw[0], hw[1])
+    else:
+        key = h.astype(_U32).reshape(-1)
+    idx, rank = hll_index_rank(key, b, rank_bits)
+    rank = jnp.where(valid.reshape(-1), rank, 0)
+    out = jnp.zeros((1 << b,), jnp.int32).at[idx].max(rank)
     return out if init is None else jnp.maximum(out, init)
 
 
 def cms_reduce(h, valid, a, b, log2_width: int, init=None) -> jnp.ndarray:
     """(B, W) masked hashes -> (depth, 2^log2_width) int32 partial counts.
 
-    Row d's column is the top ``log2_width`` bits of the affine remix
-    ``a[d]*h + b[d]`` (mod 2^32) — bit-identical to
-    ``repro.core.CountMinSketch._cols`` — and invalid (padded) windows add
-    0. Integer scatter-add is exact and order-free, so this is also the
-    Pallas fallback epilogue for tables too wide for VMEM scratch. ``init``
+    Row d's column is :func:`repro.core.sketches.cms_column` of the hash
+    under ``a[d]``, ``b[d]`` — the top ``log2_width`` bits of the affine
+    remix ``a[d]*h + b[d]`` (mod 2^32), or over two-word hashes (2, B, W)
+    its vector form with (depth, pieces) ``a`` — so it is bit-identical to
+    ``repro.core.CountMinSketch._cols``; invalid (padded) windows add 0.
+    Integer scatter-add is exact and order-free, so this is also the Pallas
+    fallback epilogue for tables too wide for VMEM scratch. ``init``
     optionally carries a running table in (counts merge by ``+``).
     """
-    hf = h.astype(_U32).reshape(-1)
     vf = valid.reshape(-1).astype(jnp.int32)
     depth = a.shape[0]
-    mixed = a[:, None] * hf[None, :] + b[:, None]
-    cols = (mixed >> np.uint32(32 - log2_width)).astype(jnp.int32)
+    cols = cms_columns(h, a, b, log2_width, words=2 if _wide(h, valid) else 1)
     rows = jnp.broadcast_to(jnp.arange(depth, dtype=jnp.int32)[:, None],
                             cols.shape)
     table = (jnp.zeros((depth, 1 << log2_width), jnp.int32) if init is None

@@ -120,13 +120,15 @@ def sharded_execute(plan: SketchPlan, mesh: Mesh, ref_path: bool, tile,
         return out
 
     row = P(AXIS)
+    # two-word lanes (2, B, S) shard their rows, axis 1
+    lanes = row if plan.hash.words == 1 else P(None, AXIS)
     out_specs = {name: P() if spec.state_kind == "global" else row
                  for name, spec in plan.sketches}
     op_specs = {name: {k: (row if k == "init" else P()) for k in v}
                 for name, v in opd.items()}
     out = shard_map(
         local, mesh=mesh,
-        in_specs=(row, row if xb is not None else None, row,
+        in_specs=(lanes, row if xb is not None else None, row,
                   row if ws is not None else None, op_specs),
         out_specs=out_specs, check_vma=False)(x, xb, nw, ws, opd)
     for name, (init, merge) in carry.items():
@@ -170,7 +172,7 @@ def run_sharded(plan: SketchPlan, h1v: jnp.ndarray, *, h1v_b=None,
                          f"{mesh.axis_names}")
     x, xb, nw, ws, operands, lead, ref_path = api.validate(
         plan, h1v, h1v_b, n_windows, operands, impl, w_start)
-    B = x.shape[0]
+    B = x.shape[-2]
     d = mesh.devices.size
     pad = -B % d
     if pad:
@@ -178,7 +180,8 @@ def run_sharded(plan: SketchPlan, h1v: jnp.ndarray, *, h1v_b=None,
         # and zero Bloom counts are sliced off below; HLL contributions are
         # rank 0, which never wins a register max. Row-level carries pad
         # alongside their rows (the pad values are sliced off with them).
-        x = _pad_rows(x, pad)
+        x = (_pad_rows(x, pad) if plan.hash.words == 1
+             else jnp.pad(x, ((0, 0), (0, pad), (0, 0))))
         if xb is not None:
             xb = _pad_rows(xb, pad)
         nw = jnp.pad(nw, (0, pad))

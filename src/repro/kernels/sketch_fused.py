@@ -43,6 +43,14 @@ Design (the grid-carried scratch-accumulator idiom):
   ``L-n+1`` bits inline (CYCLIC), so the full-width hash never exists
   outside a vector register. GENERAL keeps all L bits (pairwise independent
   as-is).
+* Two-word lanes: past ``L = 32`` (CYCLIC only) a lane is two uint32
+  words, low word first, and every lane array carries a leading word axis
+  — the h1 input ``(2, B, S)`` in ``(2, block_b, block_s)`` blocks, the
+  emitted hashes likewise. The tile's window formula rotates word pairs,
+  the discard masks each word, the HLL rank counts trailing zeros across
+  both words, and CountMin takes the vector multiply-add-shift over the
+  key's pieces (``CountMinSpec.key_pieces``). The word count is static in
+  ``HashSpec.L``, so one-word plans trace exactly the one-word code.
 
 What the TPU compiler (Mosaic) accepts shapes every epilogue:
 
@@ -62,10 +70,13 @@ What the TPU compiler (Mosaic) accepts shapes every epilogue:
   the HLL stack stays near 4 MB.
 * Bloom filters are probed by a gather, and Mosaic has no gather from a
   VMEM filter; CountMin tables wider than ``2^in_kernel_max_log2_width``
-  columns are faster as XLA's scatter-add than as a VMEM histogram. For
-  both, the kernel emits its masked window-hash tiles (and the second
-  stream's, for Bloom's probe stride) and the probe or scatter-add runs
-  *inside the same jit graph* — still one ``pallas_call`` per plan.
+  columns are faster as XLA's scatter-add than as a VMEM histogram, and
+  HLL register files whose one-hot walk passes
+  ``plan.HLL_IN_KERNEL_MAX_CELLS`` cells a window are faster as XLA's
+  scatter-max. For all three, the kernel emits its masked window-hash
+  tiles (and the second stream's, for Bloom's probe stride) and the probe,
+  scatter-add or scatter-max runs *inside the same jit graph* — still one
+  ``pallas_call`` per plan.
 * Carries: the MinHash carry seeds its scratch at ``j == 0``; the HLL,
   CountMin and Bloom carries merge with their own operator (max, add, add)
   in the XLA epilogue, which is exact on integers.
@@ -90,8 +101,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.sketches import cms_column, hll_index_rank
 from repro.kernels import ref as _kref
-from repro.kernels.cyclic import _rotl_const
+from repro.kernels.cyclic import _rotl_const, _window_words
 from repro.kernels.general import _mul_const, _xpows_host
 from repro.kernels.plan import (BloomSpec, CountMinSpec, HashSpec, HLLSpec,
                                 MinHashSpec, SketchPlan)
@@ -110,8 +122,11 @@ _BLOCK_S_DEFAULTS = {MinHashSpec: 1024, HLLSpec: 512, BloomSpec: 1024,
 
 def _tile_window_hashes(x, halo_src, *, hs: HashSpec, block_s: int):
     """Rolling window hashes of one (block_b, block_s) tile, family-generic:
-    CYCLIC unrolls constant rotations, GENERAL the clmul shift-reduce."""
+    CYCLIC unrolls constant rotations, GENERAL the clmul shift-reduce.
+    Two-word lanes come and go as ``(lo, hi)`` word tiles."""
     n, L = hs.n, hs.L
+    if hs.words == 2:
+        return _window_words(*x, *halo_src, n=n, L=L, block_s=block_s)
     if n > 1:
         cat = jnp.concatenate([x, halo_src[:, : n - 1]], axis=1)
     else:
@@ -220,19 +235,15 @@ def _hll_tile(h, valid, b: int, rank_bits: int, acc_ref, bi, j):
 
     rows = acc_ref.shape[0]
     n_rank = rank_bits + 1
-    idx = (h & np.uint32((1 << b) - 1)).astype(jnp.int32)
-    rest = h >> np.uint32(b)
-    isolated = rest & (~rest + np.uint32(1))
-    tz = jax.lax.population_count(isolated - np.uint32(1)).astype(jnp.int32)
-    rank = jnp.minimum(tz, np.int32(rank_bits)) + 1
+    idx, rank = hll_index_rank(h, b, rank_bits)
     rank = jnp.where(valid, rank, 0)                    # rank 0 never wins
-    S = h.shape[1]
+    S = valid.shape[1]
     stack = jax.lax.broadcasted_iota(jnp.int32, (n_rank * rows, S), 0)
     reg_row, rank_of = stack % rows, stack // rows + 1
     rank_col = (jax.lax.broadcasted_iota(jnp.int32, (n_rank * rows, _LANES), 0)
                 // rows + 1)
     acc = acc_ref[...]
-    for r in range(h.shape[0]):
+    for r in range(valid.shape[0]):
         ir, rr = idx[r : r + 1], rank[r : r + 1]
         left = (((ir >> 7) == reg_row) & (rr == rank_of)).astype(jnp.float32)
         seen = jnp.where(_nt_dot(left, _lo_onehot(ir)) > 0, rank_col, 0)
@@ -241,7 +252,8 @@ def _hll_tile(h, valid, b: int, rank_bits: int, acc_ref, bi, j):
     acc_ref[...] = acc
 
 
-def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, acc_ref, bi, j):
+def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, pieces: int, acc_ref,
+              bi, j):
     """Depth-major in-kernel CountMin histogram in the lane-aligned
     (depth*rows, 128) layout: per tile row and table row d, one MXU
     contraction of the column's row one-hot (weighted by validity) against
@@ -252,15 +264,17 @@ def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, acc_ref, bi, j):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    depth = a_ref.shape[0]
+    depth = b_ref.shape[0]
     rows = acc_ref.shape[0] // depth
-    shift = np.uint32(32 - log2_width)
     vf = valid.astype(jnp.float32)
-    reg_row = jax.lax.broadcasted_iota(jnp.int32, (rows, h.shape[1]), 0)
+    reg_row = jax.lax.broadcasted_iota(jnp.int32, (rows, valid.shape[1]), 0)
     for d in range(depth):
-        cols = ((a_ref[d] * h + b_ref[d]) >> shift).astype(jnp.int32)
+        # two-word keys' (depth, pieces) constants ride flat in SMEM
+        a_d = (a_ref[d] if not pieces else
+               [a_ref[d * pieces + i] for i in range(pieces)])
+        cols = cms_column(h, a_d, b_ref[d], log2_width)
         part = jnp.zeros((rows, _LANES), jnp.float32)
-        for r in range(h.shape[0]):
+        for r in range(valid.shape[0]):
             cr = cols[r : r + 1]
             left = ((cr >> 7) == reg_row).astype(jnp.float32) * vf[r : r + 1]
             part = part + _nt_dot(left, _lo_onehot(cr))
@@ -273,10 +287,13 @@ def _cms_tile(h, valid, a_ref, b_ref, log2_width: int, acc_ref, bi, j):
 # ---------------------------------------------------------------------------
 
 
-def _in_kernel(spec) -> bool:
-    """Sketches reduced inside the kernel; the others (Bloom, wide CountMin)
-    consume the emitted window hashes in the XLA epilogue."""
-    return isinstance(spec, (MinHashSpec, HLLSpec)) or (
+def _in_kernel(spec, hs: HashSpec) -> bool:
+    """Sketches reduced inside the kernel; the others (Bloom, wide CountMin,
+    HLL past its one-hot budget) consume the emitted window hashes in the
+    XLA epilogue."""
+    if isinstance(spec, HLLSpec):
+        return spec.use_in_kernel(hs)
+    return isinstance(spec, MinHashSpec) or (
         isinstance(spec, CountMinSpec) and spec.use_in_kernel)
 
 
@@ -287,7 +304,7 @@ def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
     outputs: one per in-kernel sketch, then the emitted h[, hb] tiles;
     scratch: one accumulator per in-kernel sketch."""
     hs = plan.hash
-    specs = [spec for _, spec in plan.sketches if _in_kernel(spec)]
+    specs = [spec for _, spec in plan.sketches if _in_kernel(spec, hs)]
     needs_b = plan.needs_second_stream
     it = iter(refs)
     x_ref, xh_ref = next(it), next(it)
@@ -306,14 +323,26 @@ def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
     acc_refs = [next(it) for _ in specs]
 
     bi, j = pl.program_id(0), pl.program_id(1)
-    x = x_ref[...]
-    mask = np.uint32(hs.hash_mask)
-    # ONE rolling-hash evaluation per tile, shared by every epilogue below
-    h = _tile_window_hashes(x, xh_ref[...], hs=hs, block_s=block_s) & mask
+    if hs.words == 2:
+        x, halo = (x_ref[0], x_ref[1]), (xh_ref[0], xh_ref[1])
+        shape = x[0].shape
+        # ONE rolling-hash evaluation per tile, the discard per word
+        h = tuple(hw & np.uint32(m) for hw, m in zip(
+            _tile_window_hashes(x, halo, hs=hs, block_s=block_s),
+            hs.word_masks))
+    else:
+        x = x_ref[...]
+        shape = x.shape
+        mask = np.uint32(hs.hash_mask)
+        # ONE rolling-hash evaluation per tile, shared by every epilogue below
+        h = _tile_window_hashes(x, xh_ref[...], hs=hs, block_s=block_s) & mask
     valid = _valid_mask(nw_ref[...], ws_ref[...] if has_ws else None, j,
-                        x.shape)
+                        shape)
     if emit_h:
-        h_ref[...] = h
+        if hs.words == 2:
+            h_ref[0], h_ref[1] = h
+        else:
+            h_ref[...] = h
     if needs_b:
         hb_ref[...] = _tile_window_hashes(xb_ref[...], xbh_ref[...], hs=hs,
                                           block_s=block_s) & mask
@@ -327,8 +356,8 @@ def _plan_kernel(*refs, plan: SketchPlan, block_s: int, has_ws: bool,
             _hll_tile(h, valid, spec.b, spec.resolve_rank_bits(hs), acc_ref,
                       bi, j)
         else:
-            _cms_tile(h, valid, oprs[0], oprs[1], spec.log2_width, acc_ref,
-                      bi, j)
+            _cms_tile(h, valid, oprs[0], oprs[1], spec.log2_width,
+                      spec.key_pieces(hs), acc_ref, bi, j)
 
         @pl.when((bi == pl.num_programs(0) - 1)
                  & (j == pl.num_programs(1) - 1))
@@ -357,7 +386,7 @@ def _resolve_block_s(plan: SketchPlan, S: int, block_s):
     block_s = min(block_s, max(256, 1 << int(np.ceil(np.log2(max(S, 1))))))
     n = plan.hash.n
     for _, spec in plan.sketches:
-        if isinstance(spec, HLLSpec):
+        if isinstance(spec, HLLSpec) and spec.use_in_kernel(plan.hash):
             # the HLL walk's (ranks * rows, block_s) stacked one-hot
             stacked = ((spec.resolve_rank_bits(plan.hash) + 1)
                        * _hist_rows(1 << spec.b))
@@ -376,13 +405,16 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
                       interpret: bool = False) -> dict:
     """Execute every sketch in ``plan`` in ONE rolling-hash device pass.
 
-    h1v (B, S) uint32, h1v_b (B, S) or None (required iff the plan holds a
-    BloomSpec), n_windows (B,) int32, operands {sketch_name: {operand:
-    array}} -> {sketch_name: result} with MinHash (B, k) uint32, HLL (2^b,)
-    int32 (reduced over the whole batch), Bloom (B,) int32 hit counts,
-    CountMin (depth, 2^log2_width) int32 batch partial counts (in VMEM
-    scratch up to the spec's ``in_kernel_max_log2_width``; wider tables are
-    scatter-added from kernel-emitted hashes in the same jit graph).
+    h1v (B, S) uint32 — (2, B, S) two-word lanes past L = 32 — h1v_b
+    (B, S) or None (required iff the plan holds a BloomSpec), n_windows
+    (B,) int32, operands {sketch_name: {operand: array}} -> {sketch_name:
+    result} with MinHash (B, k) uint32, HLL (2^b,) int32 (reduced over the
+    whole batch; in VMEM scratch while ``HLLSpec.use_in_kernel``,
+    else scatter-maxed from kernel-emitted hashes in the same jit graph),
+    Bloom (B,) int32 hit counts, CountMin (depth, 2^log2_width) int32 batch
+    partial counts (in VMEM scratch up to the spec's
+    ``in_kernel_max_log2_width``; wider tables are scatter-added from
+    kernel-emitted hashes in the same jit graph).
 
     A sketch's optional ``init`` operand (its ``state_struct`` shape)
     continues a running state instead of starting from the identity: the
@@ -394,12 +426,15 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     range ``[w_start, n_windows)``), masking windows that would span a
     stream chunk's zero-filled pre-history.
     """
-    assert h1v.ndim == 2 and n_windows.shape == (h1v.shape[0],)
-    B, S = h1v.shape
+    hs = plan.hash
+    wide = hs.words == 2
+    assert h1v.ndim == 1 + hs.words and (not wide or h1v.shape[0] == 2)
+    B, S = h1v.shape[-2:]
+    assert n_windows.shape == (B,)
     block_s = _resolve_block_s(plan, S, block_s)
     Bp = -(-B // block_b) * block_b
     Sp = -(-S // block_s) * block_s
-    x = jnp.pad(h1v.astype(_U32), ((0, Bp - B), (0, Sp - S)))
+    x = jnp.pad(h1v.astype(_U32), ((0, 0),) * wide + ((0, Bp - B), (0, Sp - S)))
     nw = jnp.pad(n_windows.astype(jnp.int32), (0, Bp - B))[:, None]
     grid = (Bp // block_b, Sp // block_s)
     nsb = grid[1]
@@ -411,13 +446,23 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     halo = pl.BlockSpec((block_b, block_s),
                         lambda bi, j, _n=nsb: (bi, jnp.minimum(j + 1, _n - 1)),
                         memory_space=pltpu.VMEM)
+    # lane tiles: two-word lanes take (2, block_b, block_s) blocks
+    lane_tile, lane_halo = tile, halo
+    if wide:
+        lane_tile = pl.BlockSpec((2, block_b, block_s),
+                                 lambda bi, j: (0, bi, j),
+                                 memory_space=pltpu.VMEM)
+        lane_halo = pl.BlockSpec(
+            (2, block_b, block_s),
+            lambda bi, j, _n=nsb: (0, bi, jnp.minimum(j + 1, _n - 1)),
+            memory_space=pltpu.VMEM)
     row = lambda w: pl.BlockSpec((block_b, w), lambda bi, j: (bi, 0),
                                  memory_space=pltpu.VMEM)
     whole = lambda shape: pl.BlockSpec(shape, lambda bi, j: (0, 0),
                                        memory_space=pltpu.VMEM)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    in_specs, inputs = [tile, halo], [x, x]
+    in_specs, inputs = [lane_tile, lane_halo], [x, x]
     if plan.needs_second_stream:
         assert h1v_b is not None and h1v_b.shape == h1v.shape, \
             "plans with a BloomSpec need a second hash stream h1v_b"
@@ -454,26 +499,33 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
             out_shapes.append(jax.ShapeDtypeStruct((Bp, spec.k), _U32))
             scratches.append(pltpu.VMEM((block_b, spec.k), jnp.int32))
         elif isinstance(spec, HLLSpec):
+            if not _in_kernel(spec, hs):
+                continue
             shape = (_hist_rows(1 << spec.b), _LANES)
             out_specs.append(whole(shape))
             out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.int32))
             scratches.append(pltpu.VMEM(shape, jnp.int32))
-        elif _in_kernel(spec):                       # CountMin in VMEM
+        elif _in_kernel(spec, hs):                   # CountMin in VMEM
             in_specs += [smem, smem]
-            inputs += [ops_nm["a"].astype(_U32), ops_nm["b"].astype(_U32)]
+            # two-word keys' (depth, pieces) constants ride flat in SMEM
+            inputs += [ops_nm["a"].astype(_U32).reshape(-1),
+                       ops_nm["b"].astype(_U32)]
             shape = (spec.depth * _hist_rows(spec.width), _LANES)
             out_specs.append(whole(shape))
             out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.int32))
             scratches.append(pltpu.VMEM(shape, jnp.int32))
-    # Bloom probes and wide CountMin tables consume the tile's masked window
-    # hashes in XLA (Mosaic has no gather from a VMEM filter, and XLA's
-    # scatter-add beats a VMEM histogram at 2^16 columns): the kernel emits
-    # them, the one plan output that round-trips hashes through HBM
-    emit_h = not all(_in_kernel(spec) for _, spec in plan.sketches)
-    for emitted in (emit_h, plan.needs_second_stream):
-        if emitted:
-            out_specs.append(tile)
-            out_shapes.append(jax.ShapeDtypeStruct((Bp, Sp), _U32))
+    # Bloom probes, wide CountMin tables and costly HLL walks consume the
+    # tile's masked window hashes in XLA (Mosaic has no gather from a VMEM
+    # filter, and XLA's scatter-add and scatter-max beat a VMEM histogram
+    # there): the kernel emits them, the one plan output that round-trips
+    # hashes through HBM
+    emit_h = not all(_in_kernel(spec, hs) for _, spec in plan.sketches)
+    if emit_h:
+        out_specs.append(lane_tile)
+        out_shapes.append(jax.ShapeDtypeStruct(x.shape, _U32))
+    if plan.needs_second_stream:
+        out_specs.append(tile)
+        out_shapes.append(jax.ShapeDtypeStruct((Bp, Sp), _U32))
 
     outs = list(pl.pallas_call(
         functools.partial(_plan_kernel, plan=plan, block_s=block_s,
@@ -490,12 +542,16 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
     hb_out = outs.pop() if plan.needs_second_stream else None
     h_out = outs.pop() if emit_h else None
     if h_out is not None:
-        # validity re-derived from the padded n_windows exactly as
-        # in-kernel (padded rows have nw=0, out-of-range columns are >= nw)
-        idx = jnp.arange(Sp, dtype=jnp.int32)
-        valid = idx[None, :] < nw
+        # only the caller's rows and the S-n+1 columns a window can start
+        # at are ever valid; validity re-derived from n_windows exactly as
+        # in-kernel (out-of-range columns are >= nw)
+        W = S - hs.n + 1
+        h_out = h_out[..., :B, :W]
+        hb_out = None if hb_out is None else hb_out[:B, :W]
+        idx = jnp.arange(W, dtype=jnp.int32)
+        valid = idx[None, :] < nw[:B]
         if has_ws:
-            valid &= idx[None, :] >= ws
+            valid &= idx[None, :] >= ws[:B]
     kernel_outs = iter(outs)
     results = {}
     for name, spec in plan.sketches:
@@ -504,9 +560,16 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
         if isinstance(spec, MinHashSpec):
             results[name] = next(kernel_outs)[:B]
         elif isinstance(spec, HLLSpec):
-            regs = next(kernel_outs).reshape(-1)[: 1 << spec.b]
-            results[name] = (regs if init is None
-                             else jnp.maximum(regs, init.astype(jnp.int32)))
+            init = None if init is None else init.astype(jnp.int32)
+            if _in_kernel(spec, hs):
+                regs = next(kernel_outs).reshape(-1)[: 1 << spec.b]
+                results[name] = (regs if init is None
+                                 else jnp.maximum(regs, init))
+            else:
+                with jax.named_scope("sketch.hll.scatter"):
+                    results[name] = _kref._hll_reduce(
+                        h_out, valid, spec.b, spec.resolve_rank_bits(hs),
+                        init=init)
         elif isinstance(spec, CountMinSpec):
             init = None if init is None else init.astype(jnp.int32)
             if spec.use_in_kernel:
@@ -514,9 +577,11 @@ def sketch_plan_fused(h1v: jnp.ndarray, h1v_b, n_windows: jnp.ndarray,
                 table = table[:, : spec.width]
                 results[name] = table if init is None else table + init
             else:
-                results[name] = _kref.cms_reduce(
-                    h_out, valid, ops_nm["a"].astype(_U32),
-                    ops_nm["b"].astype(_U32), spec.log2_width, init=init)
+                with jax.named_scope("sketch.cms.scatter"):
+                    results[name] = _kref.cms_reduce(
+                        h_out, valid, ops_nm["a"].astype(_U32),
+                        ops_nm["b"].astype(_U32), spec.log2_width,
+                        init=init)
         else:
             hits = _kref._bloom_reduce(h_out, hb_out, valid,
                                        ops_nm["bits"].astype(_U32), spec.k,

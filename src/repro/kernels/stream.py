@@ -37,6 +37,11 @@ How a chunk becomes windows, exactly once:
   (``jax.jit(donate_argnums=...)``): in steady state the tail/seen/sketch
   buffers are reused in place instead of reallocated per chunk.
 
+Two-word lanes (CYCLIC past ``L = 32``, ``HashSpec.words == 2``) carry a
+leading word axis on every lane array — chunks ``(2, B, C)`` or
+``(2, T, B, C)``, the tail ``(2, B, n-1)`` — so the n-1 tail carry holds
+both words of each symbol. Rows are always axis -2 of a lane array.
+
 Rows advance independently: per-chunk ``lengths`` mark how many of a row's
 chunk symbols are real, a row whose stream has ended just submits 0, and an
 idle row's tail is preserved verbatim (the tail refresh gathers at the
@@ -146,6 +151,23 @@ def _resolve_mesh(mesh, data_shards):
     return mesh
 
 
+def _lanes(plan: SketchPlan, shape) -> tuple:
+    """The shape of a lane array: a leading word axis for two-word lanes."""
+    return tuple(shape) if plan.hash.words == 1 else (2,) + tuple(shape)
+
+
+def _words_prefix(plan: SketchPlan) -> str:
+    return "" if plan.hash.words == 1 else "2, "
+
+
+def _pad_rows(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """Append ``rows`` zero rows on axis -2 (lane arrays) or 0 (``(B,)``)."""
+    axis = x.ndim - 2 if x.ndim > 1 else 0
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, rows)
+    return jnp.pad(x, pad)
+
+
 def state_batch(plan: SketchPlan, state: Dict) -> int:
     """The (possibly shard-padded) batch size a stream state was built for."""
     return state["seen"].shape[0]
@@ -156,7 +178,8 @@ def init_state(plan: SketchPlan, batch: int, *, carry: Optional[Dict] = None,
     """Fresh carry for ``batch`` parallel streams under ``plan``.
 
     The state is a flat pytree of device arrays (donate-able, checkpoint-
-    able): ``tail`` (B, n-1) uint32 last-consumed h1 values (plus ``tail_b``
+    able): ``tail`` (B, n-1) uint32 last-consumed h1 values ((2, B, n-1)
+    for two-word lanes; plus ``tail_b``
     for Bloom plans' second stream), ``seen`` (B,) int32 consumed-symbol
     count saturating at ``n-1`` (constant state: only the window-completion
     threshold matters), and ``sketch`` — one array per sketch, at the
@@ -179,7 +202,7 @@ def init_state(plan: SketchPlan, batch: int, *, carry: Optional[Dict] = None,
         raise ValueError(f"carry for sketches not in plan: {sorted(unknown)}")
     n = plan.hash.n
     with jax.named_scope("stream.init"):
-        state = {"tail": jnp.zeros((Bp, n - 1), jnp.uint32),
+        state = {"tail": jnp.zeros(_lanes(plan, (Bp, n - 1)), jnp.uint32),
                  "seen": jnp.zeros((Bp,), jnp.int32)}
         if plan.needs_second_stream:
             state["tail_b"] = jnp.zeros((Bp, n - 1), jnp.uint32)
@@ -211,11 +234,11 @@ def _update_body(plan, ref_path, mesh, tile, state, chunk, chunk_b, lengths,
     n = hs.n
     seen = state["seen"]
     # the clip backstops traced callers the concrete check can't see
-    v = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, chunk.shape[1])
+    v = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, chunk.shape[-1])
 
     def cat(tail, c):
         c = c.astype(jnp.uint32)
-        return jnp.concatenate([tail, c], axis=1) if n > 1 else c
+        return jnp.concatenate([tail, c], axis=-1) if n > 1 else c
 
     x = cat(state["tail"], chunk)
     xb = cat(state["tail_b"], chunk_b) if "tail_b" in state else None
@@ -241,7 +264,11 @@ def _update_body(plan, ref_path, mesh, tile, state, chunk, chunk_b, lengths,
     new = {"seen": jnp.minimum(seen + v, np.int32(n - 1))}
     if n > 1:
         cols = v[:, None] + jnp.arange(n - 1, dtype=jnp.int32)[None, :]
-        new["tail"] = jnp.take_along_axis(x, cols, axis=1)
+        if hs.words == 2:                 # both words of each symbol
+            new["tail"] = jnp.take_along_axis(
+                x, jnp.broadcast_to(cols, (2,) + cols.shape), axis=-1)
+        else:
+            new["tail"] = jnp.take_along_axis(x, cols, axis=1)
         if xb is not None:
             new["tail_b"] = jnp.take_along_axis(xb, cols, axis=1)
     else:
@@ -269,8 +296,8 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
     Two input layouts, selected by the static ``n_chunks``:
 
     * ``n_chunks=None`` — pre-tiled: ``x``/``xb`` are (T, B, C) chunk
-      stacks and ``lens`` is (T, B) per-chunk real-symbol counts (the
-      :func:`update_many` contract).
+      stacks ((2, T, B, C) for two-word lanes) and ``lens`` is (T, B)
+      per-chunk real-symbol counts (the :func:`update_many` contract).
     * ``n_chunks=T`` — flat: ``x``/``xb`` are (B, T*C) whole streams and
       ``lens`` is the (B,) *total* symbol budget; the tiling and the
       per-chunk length split ``clip(lens - t*C, 0, C)`` happen inside the
@@ -288,12 +315,18 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
     and commutative, so end-merging the per-shard partials is bit-identical
     to merging every chunk.
     """
+    wide = plan.hash.words == 2
     if n_chunks is None:
-        xs_x, xs_b, xs_len = x, xb, lens
+        # two-word chunk stacks scan over T with the word axis inside
+        xs_x = jnp.moveaxis(x, 0, 1) if wide else x
+        xs_b, xs_len = xb, lens
     else:
-        B = x.shape[0]
-        C = x.shape[1] // n_chunks
-        xs_x = x.reshape(B, n_chunks, C).swapaxes(0, 1)
+        B = x.shape[-2]
+        C = x.shape[-1] // n_chunks
+        if wide:
+            xs_x = x.reshape(2, B, n_chunks, C).transpose(2, 0, 1, 3)
+        else:
+            xs_x = x.reshape(B, n_chunks, C).swapaxes(0, 1)
         xs_b = (xb.reshape(B, n_chunks, C).swapaxes(0, 1)
                 if xb is not None else None)
         lo = jnp.arange(n_chunks, dtype=jnp.int32)[:, None] * np.int32(C)
@@ -335,11 +368,13 @@ def _scan_body(plan, ref_path, mesh, tile, n_chunks, state, x, xb, lens,
     row = P(shard.AXIS)
     chunk_axis = P(None, shard.AXIS)
     st_spec = {k: row for k in state if k != "sketch"}
+    if wide:                       # (2, B, n-1) tail, (T, 2, B, C) chunks
+        st_spec["tail"] = P(None, shard.AXIS)
     st_spec["sketch"] = {name: P() if spec.state_kind == "global" else row
                          for name, spec in plan.sketches}
     state = shard_map(
         local, mesh=mesh,
-        in_specs=(st_spec, chunk_axis,
+        in_specs=(st_spec, P(None, None, shard.AXIS) if wide else chunk_axis,
                   chunk_axis if xs_b is not None else None, chunk_axis),
         out_specs=st_spec, check_vma=False)(state, xs_x, xs_b, xs_len)
     out = dict(state["sketch"])
@@ -369,7 +404,8 @@ def update(plan: SketchPlan, state: Dict, chunk, *, chunk_b=None,
       state: carry from :func:`init_state` / a previous :func:`update`.
         When donation is active the passed-in state is consumed.
       chunk: (B, C) uint32 h1-mapped values, any fixed C >= 1 (each distinct
-        C is one compiled shape; keep it constant for a single-trace loop).
+        C is one compiled shape; keep it constant for a single-trace loop);
+        (2, B, C) for two-word lanes.
       chunk_b: second family draw's chunk, required iff the plan has a
         BloomSpec.
       lengths: (B,) count of *real* symbols per row in this chunk (default:
@@ -386,9 +422,10 @@ def update(plan: SketchPlan, state: Dict, chunk, *, chunk_b=None,
     mesh = _resolve_mesh(mesh, data_shards)
     ref_path = api.use_ref(impl)
     chunk = jnp.asarray(chunk)
-    if chunk.ndim != 2:
-        raise ValueError(f"chunk must be (B, C), got shape {chunk.shape}")
-    B, C = chunk.shape
+    if chunk.shape[:-2] != _lanes(plan, ()) or chunk.ndim < 2:
+        raise ValueError(f"chunk must be ({_words_prefix(plan)}B, C), got "
+                         f"shape {chunk.shape}")
+    B, C = chunk.shape[-2:]
     Bp = state_batch(plan, state)
     if B > Bp:
         raise ValueError(f"chunk rows {B} > stream state rows {Bp}")
@@ -421,9 +458,9 @@ def update(plan: SketchPlan, state: Dict, chunk, *, chunk_b=None,
         # window totals) from the clipped count the engine actually consumes
         api.check_row_counts(lengths, "lengths", upper=C)
     if B < Bp:            # shard padding rows: no symbols, carry untouched
-        chunk = jnp.pad(chunk, ((0, Bp - B), (0, 0)))
+        chunk = _pad_rows(chunk, Bp - B)
         if chunk_b is not None:
-            chunk_b = jnp.pad(chunk_b, ((0, Bp - B), (0, 0)))
+            chunk_b = _pad_rows(chunk_b, Bp - B)
         lengths = jnp.pad(lengths, (0, Bp - B))
     tile = tuple(sorted(tile_kw.items()))
     fn = _update_donated if _resolve_donate(donate) else _update_plain
@@ -446,7 +483,8 @@ def update_many(plan: SketchPlan, state: Dict, chunks, *, chunk_b=None,
     unbounded feed never retraces however long it runs.
 
     Args mirror :func:`update` with a leading chunk axis:
-      chunks: (T, B, C) uint32 h1 chunk stack, scanned in order.
+      chunks: (T, B, C) uint32 h1 chunk stack, scanned in order; (2, T, B,
+        C) for two-word lanes.
       chunk_b: (T, B, C) second family draw, iff the plan has a BloomSpec.
       lengths: (T, B) real-symbol counts per chunk (default: all C). A
         finished row submits 0 from some chunk on and its carry rides
@@ -455,10 +493,10 @@ def update_many(plan: SketchPlan, state: Dict, chunks, *, chunk_b=None,
     mesh = _resolve_mesh(mesh, data_shards)
     ref_path = api.use_ref(impl)
     chunks = jnp.asarray(chunks)
-    if chunks.ndim != 3:
-        raise ValueError(f"chunks must be (T, B, C), got shape "
-                         f"{chunks.shape}")
-    T, B, C = chunks.shape
+    if chunks.shape[:-3] != _lanes(plan, ()) or chunks.ndim < 3:
+        raise ValueError(f"chunks must be ({_words_prefix(plan)}T, B, C), "
+                         f"got shape {chunks.shape}")
+    T, B, C = chunks.shape[-3:]
     if T < 1:
         raise ValueError(f"need at least one chunk, got T={T}")
     Bp = state_batch(plan, state)
@@ -490,9 +528,9 @@ def update_many(plan: SketchPlan, state: Dict, chunks, *, chunk_b=None,
                              f"({T}, {B})")
         api.check_row_counts(lengths, "lengths", upper=C)
     if B < Bp:            # shard padding rows: no symbols, carry untouched
-        chunks = jnp.pad(chunks, ((0, 0), (0, Bp - B), (0, 0)))
+        chunks = _pad_rows(chunks, Bp - B)
         if chunk_b is not None:
-            chunk_b = jnp.pad(chunk_b, ((0, 0), (0, Bp - B), (0, 0)))
+            chunk_b = _pad_rows(chunk_b, Bp - B)
         lengths = jnp.pad(lengths, ((0, 0), (0, Bp - B)))
     tile = tuple(sorted(tile_kw.items()))
     fn = _scan_donated if _resolve_donate(donate) else _scan_plain
@@ -568,7 +606,8 @@ def export_state(plan: SketchPlan, state: Dict,
     """
     if batch is None:
         batch = state_batch(plan, state)
-    out = {k: np.asarray(state[k][:batch])
+    out = {k: np.asarray(state[k][..., :batch, :] if k == "tail"
+                         else state[k][:batch])
            for k in ("tail", "tail_b", "seen") if k in state}
     sk = {}
     for name, spec in plan.sketches:
@@ -603,10 +642,12 @@ def import_state(plan: SketchPlan, tree: Dict, *, mesh=None,
         return a
 
     tail = np.asarray(tree["tail"])
-    if tail.shape != (batch, n - 1):
-        raise ValueError(f"tail shape {tail.shape} != ({batch}, {n - 1}) — "
+    want = _lanes(plan, (batch, n - 1))
+    if tail.shape != want:
+        raise ValueError(f"tail shape {tail.shape} != {want} — "
                          f"was this state exported under a different plan?")
-    state = {"tail": rowpad(tail, 0, jnp.uint32),
+    state = {"tail": (rowpad(tail, 0, jnp.uint32) if plan.hash.words == 1
+                      else _pad_rows(jnp.asarray(tail, jnp.uint32), pad)),
              "seen": rowpad(seen, 0, jnp.int32)}
     if plan.needs_second_stream:
         if "tail_b" not in tree:
@@ -678,8 +719,8 @@ def run_stream(plan: SketchPlan, h1v, *, chunk_s: int, h1v_b=None,
     mesh = _resolve_mesh(mesh, data_shards)
     ref_path = api.use_ref(impl)
     n = plan.hash.n
-    x, lead = api.flatten(jnp.asarray(h1v))
-    B, S = x.shape
+    x, lead = api.flatten(jnp.asarray(h1v), plan.hash.words)
+    B, S = x.shape[-2:]
     xb = None
     if h1v_b is not None:
         xb, _ = api.flatten(jnp.asarray(h1v_b))
@@ -710,11 +751,11 @@ def run_stream(plan: SketchPlan, h1v, *, chunk_s: int, h1v_b=None,
     if executor == "host":
         for c in range(nc):
             lo = c * chunk_s
-            ck = x[:, lo : lo + chunk_s]
+            ck = x[..., lo : lo + chunk_s]
             ckb = xb[:, lo : lo + chunk_s] if xb is not None else None
-            if ck.shape[1] < chunk_s:   # ragged tail: same compiled shape
-                pad = chunk_s - ck.shape[1]
-                ck = jnp.pad(ck, ((0, 0), (0, pad)))
+            if ck.shape[-1] < chunk_s:  # ragged tail: same compiled shape
+                pad = chunk_s - ck.shape[-1]
+                ck = jnp.pad(ck, ((0, 0),) * (ck.ndim - 1) + ((0, pad),))
                 if ckb is not None:
                     ckb = jnp.pad(ckb, ((0, 0), (0, pad)))
             lengths = jnp.clip(sym - np.int32(lo), 0, np.int32(chunk_s))
@@ -733,14 +774,14 @@ def run_stream(plan: SketchPlan, h1v, *, chunk_s: int, h1v_b=None,
     else:                               # "scan": one dispatch, loop inside
         pad = nc * chunk_s - S
         if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)))
+            x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
             if xb is not None:
                 xb = jnp.pad(xb, ((0, 0), (0, pad)))
         operands_n = api._check_operands(plan, operands, None)
         Bp = state_batch(plan, state)
         lens = sym
         if B < Bp:        # shard padding rows: no symbols, carry untouched
-            x = jnp.pad(x, ((0, Bp - B), (0, 0)))
+            x = _pad_rows(x, Bp - B)
             if xb is not None:
                 xb = jnp.pad(xb, ((0, Bp - B), (0, 0)))
             lens = jnp.pad(lens, (0, Bp - B))

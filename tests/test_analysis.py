@@ -261,6 +261,28 @@ def test_kernel_probe_from_undiscarded_bits_is_flagged(masked):
         assert findings and "mul" in findings[0], findings
 
 
+def test_two_word_probe_from_dependent_bits_is_flagged():
+    """At two-word lanes the discard masks the high word: a rank read from
+    the raw high word (its dependent top bits) is flagged, one read from
+    the masked word is not, and the statistics plan at L = 64 is clean."""
+    from repro.kernels.plan import HashSpec
+    mask = HashSpec(family="cyclic", n=13, L=64).word_masks[1]
+
+    def bad(lo, hi):
+        kept = hi & np.uint32(mask)               # the discard site
+        return lo ^ kept ^ (hi >> np.uint32(14))  # ...but a rank from raw
+
+    def good(lo, hi):
+        kept = hi & np.uint32(mask)
+        return lo ^ (kept >> np.uint32(14))
+
+    args = (jnp.uint32(7), jnp.uint32(9))
+    findings = discard.trace_findings(jax.make_jaxpr(bad)(*args), mask)
+    assert findings and "shift_right" in findings[0], findings
+    assert discard.trace_findings(jax.make_jaxpr(good)(*args), mask) == []
+    assert discard.verify_stats_discard() == []
+
+
 def test_static_discard_rules_on_fixture(tmp_path):
     """DS1 (out_bits-shaped shift) and DS2 (unmasked probe argument) fire on
     a seeded consumer file placed inside the checker's scope."""

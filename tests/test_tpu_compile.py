@@ -157,3 +157,41 @@ def test_decode_kernel_compiles_at_cell_shape(one_chip):
     text = _decode_compiled(one_chip, 256, 163840)
     assert "tpu_custom_call" in text
     _assert_no_candidate_gather(text)
+
+
+def _two_word_plan(hll, cms):
+    return SketchPlan(HashSpec(family="cyclic", n=13, L=64),
+                      (("hll", hll), ("cms", cms)))
+
+
+def _two_word_operands(plan):
+    cms = plan.sketches[1][1]
+    return {"cms": {"a": jnp.zeros((cms.depth, cms.key_pieces(plan.hash)),
+                                   U32),
+                    "b": jnp.zeros((cms.depth,), U32)}}
+
+
+def test_two_word_in_kernel_epilogues_compile(one_chip):
+    # two-word lanes with the HLL walk and the CountMin histogram in VMEM
+    plan = _two_word_plan(HLLSpec(b=12), CountMinSpec(depth=4, log2_width=12))
+    assert plan.sketches[0][1].use_in_kernel(plan.hash)
+    x = jax.ShapeDtypeStruct((2, B, S), U32, sharding=one_chip)
+    nw = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    fn = functools.partial(sketch_plan_fused, plan=plan, interpret=False)
+    _assert_kernel(jax.jit(fn).lower(
+        x, None, nw, _sds(_two_word_operands(plan), one_chip)))
+
+
+def test_two_word_stats_stream_compiles(one_chip, compiled_as_tpu):
+    # the stream.zipf-stats plan: 52-bit keys, HLL b=14 scatter-max and
+    # CountMin 4 x 2^20 scatter-add, through update_many with the carry
+    # donated as on the chip
+    plan = _two_word_plan(HLLSpec(b=14), CountMinSpec(depth=4, log2_width=20))
+    assert not plan.sketches[0][1].use_in_kernel(plan.hash)
+    state = _sds(jax.eval_shape(lambda: stream.init_state(plan, B)), one_chip)
+    chunks = jax.ShapeDtypeStruct((2, 1, B, 2048), U32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((1, B), jnp.int32, sharding=one_chip)
+    ops = _sds(api._check_operands(plan, _two_word_operands(plan), None),
+               one_chip)
+    _assert_kernel(stream._scan_donated.lower(
+        plan, False, None, (), None, state, chunks, None, lens, ops))
