@@ -7,11 +7,13 @@ on the primed pool's carry:
 
 * ``step`` / ``step_ref`` — the whole ``SessionPool.step``, kernel path
   (``impl="auto"``) and jnp reference graph (``impl="ref"``);
-* ``decode`` / ``decode_ref`` — ``api.decode`` alone: candidate hashes,
-  Bloom probes, logit masking, mask packing;
+* ``decode`` / ``decode_ref`` — ``api.decode`` alone, as the step calls
+  it: candidate hashes, Bloom probes, logit masking, mask packing;
 * ``probe_norepeat`` / ``probe_canary`` — candidate hashes and the probes
   alone (``ref.bloom_probe_hits``) against the per-session filters / the
-  shared canary filter;
+  shared canary filter's membership table, one probe each;
+* ``canary_table`` — one build of that table, which a pool makes once and
+  no step repeats;
 * ``sample`` — the per-row categorical draw over the masked logits;
 * ``advance`` — the carry update (ring, prefix, Bloom insert) and the
   banned/canary counters.
@@ -79,7 +81,7 @@ def main(argv=None) -> int:
     pool = pools["auto"]
     # a copy: the pool donates its own carry to every step
     st = jax.tree_util.tree_map(jnp.copy, pool.state)
-    ph1, bits = pool.h1, pool.canary_bits
+    ph1, table, step_spec = pool.h1, pool.canary_table, pool.step_spec
     key = jax.random.PRNGKey(0)
     logits = jax.random.normal(key, (C, V), jnp.float32)
     keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
@@ -92,12 +94,12 @@ def main(argv=None) -> int:
         return h & np.uint32(spec.hash_mask)
 
     decode = {impl: jax.jit(lambda lg, s, impl=impl: api.decode(
-        spec, lg, s["prefix"], ready, s["bloom"], ph1, canary_bits=bits,
-        impl=impl)) for impl in ("auto", "ref")}
+        step_spec, lg, s["prefix"], ready, s["bloom"], ph1,
+        canary_bits=table, impl=impl)) for impl in ("auto", "ref")}
     probe_norepeat = jax.jit(lambda s: kref.bloom_probe_hits(
         cand(s["prefix"]), s["bloom"], spec.k, spec.log2_m))
     probe_canary = jax.jit(lambda s: kref.bloom_probe_hits(
-        cand(s["prefix"]), bits, spec.canary_k, spec.canary_log2_m))
+        cand(s["prefix"]), table, 1, spec.canary_log2_m))
     sample = jax.jit(lambda lg: jax.vmap(
         lambda k, l: jax.random.categorical(k, l / 1.0))(keys, lg))
 
@@ -120,6 +122,8 @@ def main(argv=None) -> int:
         "decode_ref": lambda: decode["ref"](logits, st),
         "probe_norepeat": lambda: probe_norepeat(st),
         "probe_canary": lambda: probe_canary(st),
+        "canary_table": lambda: kref.bloom_membership_table(
+            pool.canary_bits, spec.canary_k, spec.canary_log2_m),
         "sample": lambda: sample(masked["logits"]),
         "advance": lambda: advance(st, token, masked["banned"],
                                    masked["canary"]),

@@ -278,9 +278,9 @@ def phase_decode(dev, capacity=1024, prompt=512, steps=32):
     with Clock() as ref_clock:
         (want,) = _decode_run([ref], prompts, steps, V)
     kernel = has_kernel(
-        sessions._step_donated, spec, False, None, (), 1.0, 0, pool.state,
-        jax.ShapeDtypeStruct((capacity, V), jnp.float32), pool.h1,
-        pool.canary_bits, jax.random.PRNGKey(SEED), jnp.int32(0))
+        sessions._step_donated, pool.step_spec, False, None, (), 1.0, 0,
+        pool.state, jax.ShapeDtypeStruct((capacity, V), jnp.float32),
+        pool.h1, pool.canary_table, jax.random.PRNGKey(SEED), jnp.int32(0))
     banned = int(pool.state["banned_lo"].sum())
     canary_hits = int(pool.state["canary_lo"].sum())
     print(f"[decode] vocab={V} sessions={capacity} steps={steps} "
@@ -381,9 +381,10 @@ def phase_pool_sharded(dev, d=4, capacity=1024, prompt=512, steps=8):
         (got,) = _decode_run([pool], prompts, steps, V)
     (want,) = _decode_run([one], prompts, steps, V)
     kernel = has_kernel(
-        sessions._step_donated, spec, False, shard.data_mesh(d), (), 1.0, 0,
-        pool.state, jax.ShapeDtypeStruct((capacity, V), jnp.float32),
-        pool.h1, pool.canary_bits, jax.random.PRNGKey(SEED), jnp.int32(0))
+        sessions._step_donated, pool.step_spec, False, shard.data_mesh(d),
+        (), 1.0, 0, pool.state,
+        jax.ShapeDtypeStruct((capacity, V), jnp.float32), pool.h1,
+        pool.canary_table, jax.random.PRNGKey(SEED), jnp.int32(0))
     checks = {"tokens": same(got, want), "carry": same(pool.state, one.state)}
     return report("session_pool x4", clock,
                   f"{capacity * steps} tokens x {V} candidates", checks,

@@ -302,12 +302,15 @@ def verify_decode_discard(spec=None) -> List[DiscardFinding]:
                 logits, prefix, ready, bloom, h1)
         check(f"api.decode impl={impl}", jx)
 
-    state = sess.init_state(spec, B)
+    # the step as a pool runs it: the canary answered by its membership
+    # table, with the pool's step spec
+    pool = sess.SessionPool(spec, B, h1, canary_bits=cb)
     key, t = jax.random.PRNGKey(0), jnp.int32(0)
     jx = jax.make_jaxpr(
         lambda st, lg, h, k, tt: sess._step_body(
-            spec, False, None, (), 0.8, 5, st, lg, h, cb, k, tt))(
-        state, logits, h1, key, t)
+            pool.step_spec, False, None, (), 0.8, 5, st, lg, h,
+            pool.canary_table, k, tt))(
+        pool.state, logits, pool.h1, key, t)
     check("SessionPool.step", jx)
     return findings
 

@@ -15,6 +15,8 @@ XOR of word-pair rotations (:func:`_rotl_pair`).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -312,6 +314,35 @@ def bloom_probe_hits(h, words, k: int, log2_m: int) -> jnp.ndarray:
                 axis=1).reshape(word.shape)
         hit = hit & (((got >> (probe & np.uint32(31))) & np.uint32(1)) == 1)
     return hit
+
+
+# residues of one block of bloom_membership_table: the build holds at most
+# this many candidates in flight, whatever the filter's size
+_TABLE_BLOCK = 1 << 20
+
+
+@functools.partial(jax.jit, static_argnames=("k", "log2_m"))
+def bloom_membership_table(words, k: int, log2_m: int) -> jnp.ndarray:
+    """The membership table of one shared filter ``words``
+    (2^log2_m/32,): (2^log2_m/32,) uint32 words, packed like the filter,
+    whose bit ``r`` is ``bloom_probe_hits(r, words, k, log2_m)``.
+
+    Every probe of a masked hash ``h`` depends only on ``h mod 2^log2_m``,
+    and the one probe of a one-probe test is ``h & (2^log2_m - 1)``, so
+    ``bloom_probe_hits(h, table, 1, log2_m)`` answers exactly as
+    ``bloom_probe_hits(h, words, k, log2_m)``: one read for k. The table
+    is built in blocks of residues, so a 2^30-bit filter never holds 2^30
+    residues at once."""
+    block = min(_TABLE_BLOCK, 1 << log2_m)
+    words = jnp.asarray(words, _U32)
+
+    def one_block(base):
+        residues = base + jnp.arange(block, dtype=_U32)
+        return pack_mask_u32(bloom_probe_hits(residues, words, k, log2_m))
+
+    bases = np.arange((1 << log2_m) // block, dtype=np.uint32) * np.uint32(
+        block)
+    return jax.lax.map(one_block, bases).reshape(-1)
 
 
 def decode_masks_ref(logits, prefix, ready, bloom, h1, *, n: int, L: int,

@@ -36,6 +36,7 @@ index) before the shard region.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Optional, Sequence
 
@@ -60,7 +61,9 @@ _U32 = jnp.uint32
 # same contract as kernels.stream.dispatch_count), so the
 # one-dispatch-per-decode-step property is assertable against it; decode
 # steps whose kernel probes every filter from VMEM also count as
-# ``sessions.vmem_probe_steps``
+# ``sessions.vmem_probe_steps``, and each build of a canary filter's
+# membership table as ``sessions.canary_table_builds`` (one per pool
+# construction or import, none per step)
 
 
 def dispatch_count() -> int:
@@ -165,14 +168,16 @@ def _popcount_rows(packed):
 
 
 def _step_core(spec: DecodeSpec, ref_path: bool, tile, temperature: float,
-               top_k: int, state, logits, keys, h1, canary_bits):
+               top_k: int, state, logits, keys, h1, canary_table):
     """The whole decode step, purely per-row: fused epilogue -> sample ->
-    advance -> telemetry. Traceable; embedded either directly in the jitted
-    step or inside its shard_map region."""
+    advance -> telemetry. ``canary_table`` is the canary filter's
+    membership table, probed once (``spec.canary_k == 1``). Traceable;
+    embedded either directly in the jitted step or inside its shard_map
+    region."""
     live = state["active"] != 0
     ready = (state["count"] >= spec.n - 1) & live
     out = api.decode(spec, logits, state["prefix"], ready, state["bloom"],
-                     h1, canary_bits=canary_bits,
+                     h1, canary_bits=canary_table,
                      impl="ref" if ref_path else "pallas", **dict(tile))
     masked = out["logits"]
     with jax.named_scope("decode.sample"):
@@ -205,7 +210,7 @@ def _step_core(spec: DecodeSpec, ref_path: bool, tile, temperature: float,
 
 
 def _step_body(spec, ref_path, mesh, tile, temperature, top_k,
-               state, logits, h1, canary_bits, key, t):
+               state, logits, h1, canary_table, key, t):
     """One decode step = ONE device dispatch. Per-row keys are derived from
     (key, step, slot) BEFORE the shard region so sampling is bit-identical
     at any device count; under a mesh the entire core runs shard_map'd
@@ -217,9 +222,9 @@ def _step_body(spec, ref_path, mesh, tile, temperature, top_k,
     core = functools.partial(_step_core, spec, ref_path, tile, temperature,
                              top_k)
     if mesh is None:
-        return core(state, logits, keys, h1, canary_bits)
+        return core(state, logits, keys, h1, canary_table)
     return shard.rowwise(core, mesh, n_row=3)(state, logits, keys, h1,
-                                              canary_bits)
+                                              canary_table)
 
 
 # donation twins (the stream.py idiom): the carry (arg 6) is donated in
@@ -285,6 +290,11 @@ class SessionPool:
       h1: (V,) uint32 symbol hash table (one family draw); masked to L
         bits once here, so the recursion and the kernel agree bit-for-bit.
       canary_bits: shared decontam canary filter iff ``spec.has_canary``.
+        The pool builds its membership table
+        (:func:`repro.kernels.ref.bloom_membership_table`) once, here and
+        in :meth:`import_state`, and every step answers a candidate with
+        one probe of the table (``step_spec``, ``canary_k=1``) where the
+        filter takes ``canary_k``: the same answers.
       impl / donate / mesh / data_shards / tile_kw: the engine-wide knobs,
         same contract as the streaming executor.
     """
@@ -318,18 +328,32 @@ class SessionPool:
             h1 = h1 & np.uint32((1 << spec.L) - 1)
         self.h1 = h1
         self.vocab = int(h1.shape[0])
+        # the spec the step's kernel runs: the canary, if any, answered by
+        # one probe of its membership table
+        self.step_spec = dataclasses.replace(spec, canary_k=1)
+        self.canary_bits = self.canary_table = None
         if spec.has_canary:
             if canary_bits is None:
                 raise ValueError("spec has a canary filter: pass canary_bits")
-            self.canary_bits = jnp.asarray(canary_bits, _U32)
-        else:
-            if canary_bits is not None:
-                raise ValueError("canary_bits given but spec.canary_log2_m "
-                                 "== 0")
-            self.canary_bits = None
+            self._set_canary(canary_bits)
+        elif canary_bits is not None:
+            raise ValueError("canary_bits given but spec.canary_log2_m == 0")
         self.state = init_state(spec, self.capacity)
         self._free = list(range(self.capacity - 1, -1, -1))  # pop() -> slot 0 first
         self._t = 0
+
+    def _set_canary(self, canary_bits) -> None:
+        """Adopt a canary filter and build its membership table, together,
+        so the table the steps probe is never stale against the filter."""
+        bits = jnp.asarray(canary_bits, _U32)
+        if bits.shape != (self.spec.canary_words,):
+            raise ValueError(f"canary_bits shape {bits.shape} != "
+                             f"({self.spec.canary_words},) for "
+                             f"canary_log2_m={self.spec.canary_log2_m}")
+        self.canary_bits = bits
+        self.canary_table = _kref.bloom_membership_table(
+            bits, self.spec.canary_k, self.spec.canary_log2_m)
+        obs.count("sessions.canary_table_builds")
 
     # -- churn ------------------------------------------------------------
     def _mask(self, slots) -> jnp.ndarray:
@@ -409,10 +433,10 @@ class SessionPool:
         obs.count("sessions.dispatches")
         if self._vmem_probes:
             obs.count("sessions.vmem_probe_steps")
-        token, self.state = fn(self.spec, self._ref_path, self.mesh,
+        token, self.state = fn(self.step_spec, self._ref_path, self.mesh,
                                self._tile, float(temperature), int(top_k),
                                self.state, logits, self.h1,
-                               self.canary_bits, key,
+                               self.canary_table, key,
                                jnp.int32(self._t))
         self._t += 1
         return token
@@ -451,7 +475,7 @@ class SessionPool:
             if "canary_bits" not in params:
                 raise ValueError("spec has a canary filter but the snapshot "
                                  "carries no canary_bits")
-            self.canary_bits = jnp.asarray(params["canary_bits"], _U32)
+            self._set_canary(params["canary_bits"])
         carry = jax.tree_util.tree_map(jnp.asarray, tree["carry"])
         if int(carry["active"].shape[0]) != self.capacity:
             raise ValueError(

@@ -93,13 +93,43 @@ def test_fused_vmem_probes_bitparity_vs_oracle(case):
                    impl="ref")
     b = api.decode(spec, logits, prefix, ready, bloom, h1, canary_bits=cb,
                    impl="pallas", block_b=block_b)
-    assert set(a) == set(b)
-    for key in a:
-        np.testing.assert_array_equal(np.asarray(a[key]), np.asarray(b[key]),
-                                      err_msg=key)
+    # the pool's path: the canary's membership table, probed once
+    pool = sess.SessionPool(spec, 1, h1, canary_bits=cb)
+    c = api.decode(pool.step_spec, logits, prefix, ready, bloom, h1,
+                   canary_bits=pool.canary_table, impl="pallas",
+                   block_b=block_b)
+    for got in (b, c):
+        assert set(a) == set(got)
+        for key in a:
+            np.testing.assert_array_equal(np.asarray(a[key]),
+                                          np.asarray(got[key]), err_msg=key)
     assert np.asarray(a["banned"]).any()
     if canary:
         assert np.asarray(a["canary"]).any()
+
+
+@pytest.mark.parametrize("log2_m", [5, 14, 20, 21])
+@pytest.mark.parametrize("k", [1, 2, 4, 8])
+def test_membership_table_answers_as_direct_probes(k, log2_m):
+    """Bit r of ``ref.bloom_membership_table`` is the k-probe test of
+    residue r, so one probe of the table answers every full-width key as
+    the k probes of the filter do."""
+    rng = np.random.default_rng(k * 100 + log2_m)
+    words = jnp.asarray(rng.integers(0, 2**32, size=(1 << log2_m) // 32,
+                                     dtype=np.uint32))
+    table = ref.bloom_membership_table(words, k, log2_m)
+    assert table.shape == words.shape and table.dtype == jnp.uint32
+    residues = jnp.arange(min(1 << log2_m, 4096), dtype=jnp.uint32)
+    bits = np.unpackbits(np.asarray(table).view(np.uint8), bitorder="little")
+    np.testing.assert_array_equal(
+        bits[: residues.shape[0]].astype(bool),
+        np.asarray(ref.bloom_probe_hits(residues, words, k, log2_m)))
+    keys = jnp.asarray(rng.integers(0, 2**32, size=20000, dtype=np.uint32))
+    want = np.asarray(ref.bloom_probe_hits(keys, words, k, log2_m))
+    np.testing.assert_array_equal(
+        np.asarray(ref.bloom_probe_hits(keys, table, 1, log2_m)), want)
+    if log2_m > 5 and k <= 4:      # a 32-bit filter's residues may all miss
+        assert want.any() and not want.all()
 
 
 @pytest.mark.parametrize("L", [16, 32])
@@ -435,6 +465,91 @@ def test_telemetry_snapshot_matches_manual_counts():
     assert snap["canary_hits"] == want_canary
     assert snap["decode_steps"] == 6 * C
     assert 0 < snap["bloom_fill_mean"] <= snap["bloom_fill_max"] < 1
+
+
+def _direct_canary_run(pool, spec, canary, rng, steps):
+    """Step ``pool`` greedily and check every step against the direct
+    k-probe oracle (``api.decode(impl="ref")`` on the canary filter itself):
+    the same tokens, and each session's banned and canary totals as the
+    oracle counts them. Returns the canary hits of the run."""
+    st = jax.device_get(pool.state)
+    totals = lambda st, name: telemetry.u64(st[f"{name}_lo"],
+                                            st[f"{name}_hi"])
+    want = {name: totals(st, name) for name in ("banned", "canary")}
+    start = want["canary"].sum()
+    for _ in range(steps):
+        logits = rng.standard_normal((pool.capacity, pool.vocab)).astype(
+            np.float32)
+        out = api.decode(spec, logits, st["prefix"],
+                         (st["count"] >= spec.n - 1) & (st["active"] != 0),
+                         st["bloom"], pool.h1, canary_bits=canary,
+                         impl="ref")
+        for name in want:
+            want[name] += np.unpackbits(
+                np.asarray(out[name]).view(np.uint8), axis=-1).sum(
+                    axis=-1).astype(np.uint64)
+        np.testing.assert_array_equal(
+            np.asarray(pool.step(logits, temperature=0.0)),
+            np.asarray(jnp.argmax(out["logits"], axis=-1)))
+        st = jax.device_get(pool.state)
+    for name in want:
+        np.testing.assert_array_equal(totals(st, name), want[name],
+                                      err_msg=name)
+    return int(want["canary"].sum() - start)
+
+
+@pytest.mark.parametrize("impl,canary_log2_m", [
+    ("ref", 10), ("pallas", 10), ("pallas", 21)])
+def test_pool_canary_table_matches_direct_probes(impl, canary_log2_m):
+    """A pool answers the canary from its membership table, one probe per
+    candidate, on every path (the in-VMEM kernel, the XLA probes past
+    the VMEM bound, the jnp graph): tokens, banned and canary totals
+    equal the direct canary_k-probe oracle's over several steps."""
+    spec = DecodeSpec(n=3, log2_m=9, canary_log2_m=canary_log2_m)
+    V, C = 300, 8
+    rng = np.random.default_rng(canary_log2_m)
+    h1 = rng.integers(0, 2**32, size=V, dtype=np.uint32)
+    canary = rng.integers(0, 2**32, size=spec.canary_words, dtype=np.uint32)
+    pool = sess.SessionPool(spec, C, h1, canary_bits=canary, impl=impl)
+    assert pool.step_spec.canary_k == 1 and spec.canary_k == 4
+    pool.admit(C)
+    pool.prime(rng.integers(0, V, size=(C, 4), dtype=np.int32))
+    assert _direct_canary_run(pool, spec, canary, rng, 4) > 0
+
+
+def test_pool_import_answers_with_the_snapshot_canary():
+    """``import_state`` rebuilds the membership table from the snapshot's
+    filter (a stale table would answer with the old one); the table is
+    built once per construction and once per import, never per step."""
+    spec = DecodeSpec(n=3, log2_m=9, canary_log2_m=10)
+    V, C = 200, 4
+    rng = np.random.default_rng(31)
+    h1 = rng.integers(0, 2**32, size=V, dtype=np.uint32)
+    old, new = (rng.integers(0, 2**32, size=spec.canary_words,
+                             dtype=np.uint32) for _ in range(2))
+    b0 = obs.counter("sessions.canary_table_builds")
+    src = sess.SessionPool(spec, C, h1, canary_bits=new)
+    pool = sess.SessionPool(spec, C, h1, canary_bits=old)
+    assert obs.counter("sessions.canary_table_builds") - b0 == 2
+    for p in (src, pool):
+        p.admit(C)
+        p.prime(rng.integers(0, V, size=(C, 4), dtype=np.int32))
+    _direct_canary_run(pool, spec, old, rng, 2)
+    pool.import_state(src.export_state())
+    assert obs.counter("sessions.canary_table_builds") - b0 == 3
+    np.testing.assert_array_equal(np.asarray(pool.canary_bits), new)
+    np.testing.assert_array_equal(np.asarray(pool.canary_table),
+                                  np.asarray(src.canary_table))
+    _direct_canary_run(pool, spec, new, rng, 3)
+    assert obs.counter("sessions.canary_table_builds") - b0 == 3
+    # the snapshot keeps the filter, not the table
+    snap = pool.export_state()
+    np.testing.assert_array_equal(snap["params"]["canary_bits"], new)
+    # a filter of another size would build a table from clamped reads
+    snap["params"]["canary_bits"] = new[:-1]
+    with pytest.raises(ValueError, match="canary_bits shape"):
+        pool.import_state(snap)
+    assert obs.counter("sessions.canary_table_builds") - b0 == 3
 
 
 # ---------------------------------------------------------------------------

@@ -13,17 +13,21 @@ process may load the TPU library, and every test worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import api, stream
 from repro.kernels.decode import decode_masks_fused
+from repro.kernels.ref import bloom_membership_table
 from repro.kernels.plan import (BloomSpec, CountMinSpec, DecodeSpec, HashSpec,
                                 HLLSpec, MinHashSpec, SketchPlan)
 from repro.kernels.sketch_fused import sketch_plan_fused
+from repro.serve import sessions as sess
 
 B, S = 64, 4096
 U32 = jnp.uint32
@@ -126,10 +130,13 @@ def test_grid_stream_compiles(one_chip, compiled_as_tpu):
         plan, False, None, (("block_s", 512),), state, x, x, lens, ops))
 
 
-def _decode_compiled(one_chip, sessions, V):
+def _decode_compiled(one_chip, sessions, V, canary_k=4):
     """``decode_masks_fused`` compiled at (sessions, V) with the decode
-    cell's plane: both Bloom filters probed inside the kernel."""
-    spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20)
+    cell's plane: both Bloom filters probed inside the kernel. A
+    ``SessionPool`` step probes its canary's membership table once
+    (``canary_k=1``)."""
+    spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20,
+                      canary_k=canary_k)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn = functools.partial(decode_masks_fused, spec=spec, interpret=False)
     return jax.jit(
@@ -139,8 +146,13 @@ def _decode_compiled(one_chip, sessions, V):
             s((V,), U32), s((spec.canary_words,), U32)).compile().as_text()
 
 
-def _assert_no_candidate_gather(text):
-    gathers = [line for line in text.splitlines() if " gather(" in line]
+def _assert_no_candidate_gather(text, min_values=1):
+    """No XLA gather yields ``min_values`` or more values: the probes are
+    lane gathers inside the kernel."""
+    gathers = [line for line in text.splitlines()
+               if " gather(" in line and np.prod([int(d) for d in re.search(
+                   r"= \w+\[([\d,]*)\]", line).group(1).split(",") if d],
+                   dtype=np.int64) >= min_values]
     assert not gathers, gathers[:3]
 
 
@@ -152,11 +164,44 @@ def test_decode_kernel_compiles(one_chip):
 
 
 def test_decode_kernel_compiles_at_cell_shape(one_chip):
-    # decode.kimi-k2-256: 256 sessions over Kimi-K2's 163840 candidates;
-    # the probes are lane gathers inside the kernel, no XLA gather remains
-    text = _decode_compiled(one_chip, 256, 163840)
+    # decode.kimi-k2-256: 256 sessions over Kimi-K2's 163840 candidates,
+    # as the pool's step runs it (one probe of the canary's table); the
+    # probes are lane gathers inside the kernel, no XLA gather remains
+    text = _decode_compiled(one_chip, 256, 163840, canary_k=1)
     assert "tpu_custom_call" in text
     _assert_no_candidate_gather(text)
+
+
+def test_decode_step_compiles_at_cell_shape(one_chip, compiled_as_tpu):
+    # the whole SessionPool step of decode.kimi-k2-256 (kernel, sampling,
+    # advance) with the pool's step spec and canary table: no XLA gather
+    # over the candidates (the advance's h1[token] gathers one per session),
+    # and the table's build is not in the step
+    C, V = 256, 163840
+    spec = DecodeSpec(n=4, log2_m=14, k=2, canary_log2_m=20)
+    pool = sess.SessionPool(spec, 8, np.zeros(V, np.uint32),
+                            canary_bits=np.zeros(spec.canary_words,
+                                                 np.uint32))
+    s = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    text = sess._step_donated.lower(
+        pool.step_spec, False, None, (), 1.0, 0,
+        _sds(sess.init_state(spec, C), one_chip),
+        jax.ShapeDtypeStruct((C, V), jnp.float32, sharding=one_chip),
+        s(pool.h1), s(pool.canary_table), s(jax.random.PRNGKey(0)),
+        s(jnp.int32(0))).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    _assert_no_candidate_gather(text, min_values=V)
+
+
+@pytest.mark.parametrize("log2_m", [20, 30])
+def test_canary_table_build_compiles(one_chip, log2_m):
+    # the membership table of a canary_k=4 filter: its own jit, outside
+    # the step; built in blocks of residues, so a 2^30-bit filter's build
+    # holds far less than its 4 GiB of residues
+    words = jax.ShapeDtypeStruct(((1 << log2_m) // 32,), U32,
+                                 sharding=one_chip)
+    compiled = bloom_membership_table.lower(words, 4, log2_m).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 def _two_word_plan(hll, cms):
